@@ -44,8 +44,10 @@ Mapping (see DESIGN.md §7):
                                     CostModel picks a heterogeneous
                                     per-mode comm-backend map
 
-Multi-device benches run in a subprocess with 8 placeholder host devices so
-this process keeps the 1-device view (dry-run isolation rule).
+Multi-device benches run in a child process (8 simulated host devices on the
+CPU). ``run_benches`` runs them first and queries the device for the
+provenance stamp last, so this process touches no accelerator while a child
+may need it.
 
 Discover bench names with ``--list``; run a subset by naming benches on the
 command line (``python benchmarks/run.py plan_cache scheduler_overlap``).
@@ -141,12 +143,21 @@ _DIST_BENCH_BODY = """
 """
 
 
+def _in_child(bench):
+    """Mark a bench whose work runs in a child process: ``run_benches`` runs
+    these before any bench that computes with JAX in this process, because
+    a process that has touched an accelerator holds it."""
+    bench.in_child = True
+    return bench
+
+
 def _run_subprocess_bench(body: str, devices: int = 8) -> dict:
     import json
 
     script = textwrap.dedent(f"""
-        import os
-        os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count={devices}"
+        from repro.runtime import enable_compile_cache, simulate_cpu_devices
+        simulate_cpu_devices({devices})
+        enable_compile_cache()
     """) + textwrap.dedent(body)
     env = dict(os.environ)
     env["PYTHONPATH"] = _SRC
@@ -161,6 +172,7 @@ def _run_subprocess_bench(body: str, devices: int = 8) -> dict:
     raise RuntimeError(f"no JSON in output:\n{res.stdout[-2000:]}")
 
 
+@_in_child
 def bench_hooi_time() -> None:
     out = _run_subprocess_bench(_DIST_BENCH_BODY % (DIST_SCHEMES,))
     for tname, per in out.items():
@@ -491,6 +503,7 @@ _PLAN_CACHE_BODY = """
 """
 
 
+@_in_child
 def bench_plan_cache() -> None:
     """Acceptance: the second dist_hooi call on the same tensor must skip
     partition construction (host-side partition time ~ 0)."""
@@ -590,6 +603,7 @@ _SCHED_OVERLAP_BODY = """
 """
 
 
+@_in_child
 def bench_scheduler_overlap() -> None:
     """Acceptance: the scheduler pipeline (host partitioning overlapped
     with device sweeps) beats the sequential plan+sweep sum on a queue of
@@ -652,6 +666,7 @@ _EXEC_REUSE_BODY = """
 """
 
 
+@_in_child
 def bench_executor_reuse() -> None:
     """Acceptance: the second HooiExecutor.run() on a cached plan performs
     no new jit compilations and no new host->device uploads; the measured
@@ -745,6 +760,7 @@ _POOL_THROUGHPUT_BODY = """
 """
 
 
+@_in_child
 def bench_pool_throughput() -> None:
     """Acceptance: a 2-executor pool on disjoint device slices serves a
     queue of concurrent streams at higher throughput (streams/sec) than a
@@ -887,6 +903,7 @@ _OBJECTIVES_BODY = """
 """
 
 
+@_in_child
 def bench_objectives() -> None:
     """Acceptance: masked completion beats the unmasked Tucker baseline on
     held-out RMSE when a fraction of stored entries is corrupted; NN-ADMM
@@ -1021,6 +1038,7 @@ _SKETCH_WARMSTART_BODY = """
 """
 
 
+@_in_child
 def bench_sketch_warmstart() -> None:
     """Acceptance: the sketched range-finder warm start cuts counted
     oracle Z passes >=1.5x vs the full Golub-Kahan budget at equal final
@@ -1102,6 +1120,7 @@ _MIXED_BACKENDS_BODY = """
 """
 
 
+@_in_child
 def bench_mixed_backends() -> None:
     """Acceptance: ``path="auto"`` under a CostModel with skewed
     per-backend bandwidths picks a *heterogeneous* per-mode comm-backend
@@ -1192,6 +1211,7 @@ _STOCH_REFRESH_BODY = """
 """
 
 
+@_in_child
 def bench_stochastic_refresh() -> None:
     """Acceptance for the stochastic-refine rung: over a 6-batch append
     stream, sampled refines cut per-append device time >= 3x vs full
@@ -1245,15 +1265,16 @@ BENCHES = [
     bench_kernel_ttm,
     bench_kernel_roofline,
     bench_auto_selection,
-    bench_plan_cache,  # subprocess, 8 devices
-    bench_executor_reuse,  # subprocess, 8 devices
-    bench_scheduler_overlap,  # subprocess, 8 devices
-    bench_pool_throughput,  # subprocess, 8 devices
-    bench_objectives,  # subprocess, 8 devices
-    bench_sketch_warmstart,  # subprocess, 8 devices
-    bench_mixed_backends,  # subprocess, 8 devices
-    bench_stochastic_refresh,  # subprocess, 8 devices
-    bench_hooi_time,  # slowest (subprocess, 8 devices) — last
+    # the child-process benches below run before all of the above
+    bench_plan_cache,
+    bench_executor_reuse,
+    bench_scheduler_overlap,
+    bench_pool_throughput,
+    bench_objectives,
+    bench_sketch_warmstart,
+    bench_mixed_backends,
+    bench_stochastic_refresh,
+    bench_hooi_time,  # slowest child
 ]
 
 
@@ -1262,9 +1283,13 @@ def bench_environment() -> dict:
 
     Bench artifacts accumulate across PRs; without the git SHA, timestamp,
     jax version and device kind they are not comparable as a trajectory.
-    Every field degrades to a sentinel rather than failing the bench run.
+    Only the git SHA degrades to a sentinel (a copy without ``.git``); a
+    device that cannot be queried raises — an artifact must never claim a
+    device it did not see.
     """
     import datetime
+
+    import jax
 
     try:
         sha = subprocess.run(
@@ -1274,24 +1299,13 @@ def bench_environment() -> dict:
         ).stdout.strip() or "unknown"
     except Exception:  # noqa: BLE001 — git absent / not a checkout
         sha = "unknown"
-    try:
-        import jax
-
-        jax_version = jax.__version__
-    except Exception:  # noqa: BLE001
-        jax_version = "unknown"
-    try:
-        dev = jax.devices()[0]  # may raise even when jax imports fine
-        device_kind = getattr(dev, "device_kind", "unknown")
-        platform = getattr(dev, "platform", jax.default_backend())
-    except Exception:  # noqa: BLE001
-        device_kind = platform = "unknown"
+    dev = jax.devices()[0]
     return {
         "git_sha": sha,
         "timestamp": datetime.datetime.now(datetime.timezone.utc).isoformat(),
-        "jax_version": jax_version,
-        "device_kind": device_kind,
-        "platform": platform,
+        "jax_version": jax.__version__,
+        "device_kind": dev.device_kind,
+        "platform": dev.platform,
     }
 
 
@@ -1328,17 +1342,18 @@ def run_benches(benches, out_dir: str | None = None) -> list[str]:
     green". A bench (or a buggy artifact path) that drops ``BENCH_*.json``
     files *outside* ``out_dir`` is also refused loudly: stray artifacts
     in the working or benchmarks directory would never be uploaded, and
-    the perf trajectory would silently lose its data points. Returns the
-    written paths."""
+    the perf trajectory would silently lose its data points. Benches marked
+    ``_in_child`` run first and the environment stamp is taken after the
+    last bench, so no child waits on an accelerator this process holds.
+    Returns the written paths."""
     import glob
     import json
 
-    benches = list(benches)
+    benches = sorted(benches, key=lambda b: not getattr(b, "in_child", False))
     if not benches:
         raise ValueError(
             "run_benches() got an empty bench list — refusing to silently "
             "produce no artifacts (check the bench selection/filter)")
-    meta = bench_environment()
     out_dir = out_dir or os.environ.get("BENCH_OUT_DIR") or "."
     os.makedirs(out_dir, exist_ok=True)
     out_real = os.path.realpath(out_dir)
@@ -1348,7 +1363,7 @@ def run_benches(benches, out_dir: str | None = None) -> list[str]:
                             os.path.abspath(__file__)))} - {out_real})
     before = {d: set(glob.glob(os.path.join(d, "BENCH_*.json")))
               for d in scan_dirs}
-    written = []
+    results = []
     for bench in benches:
         _ROWS.clear()
         err = None
@@ -1360,11 +1375,14 @@ def run_benches(benches, out_dir: str | None = None) -> list[str]:
             _row(bench.__name__, -1.0, f"ERROR={err}")
         dt = time.perf_counter() - t0
         print(f"# {bench.__name__} took {dt:.1f}s", file=sys.stderr)
-        path = _artifact_path(out_dir, bench.__name__)
+        results.append({"bench": bench.__name__, "took_s": dt,
+                        "error": err, "rows": list(_ROWS)})
+    meta = bench_environment()
+    written = []
+    for res in results:
+        path = _artifact_path(out_dir, res["bench"])
         with open(path, "w") as f:
-            json.dump({"bench": bench.__name__, "took_s": dt,
-                       "error": err, "meta": meta, "rows": list(_ROWS)},
-                      f, indent=1)
+            json.dump(dict(res, meta=meta), f, indent=1)
         written.append(path)
     stray = sorted(p for d in scan_dirs
                    for p in set(glob.glob(os.path.join(d, "BENCH_*.json")))
@@ -1422,6 +1440,9 @@ def main(argv: list[str] | None = None) -> None:
                  "(supported: --list, --out-dir DIR, bench names)")
     names = list(argv)
     benches = select_benches(names) if names else BENCHES
+    from repro.runtime import enable_compile_cache
+
+    enable_compile_cache()
     print("name,us_per_call,derived")
     run_benches(benches, out_dir)
 

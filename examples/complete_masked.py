@@ -24,6 +24,7 @@ from repro.core.coo import SparseTensor, write_tns
 from repro.core.hooi import hooi
 from repro.data.frostt import iter_tns_batches, stream_tns
 from repro.engine.objective import holdout_mask, predict_at_coords
+from repro.runtime import enable_compile_cache
 
 
 def lowrank_sample(rng, shape, rank, nnz):
@@ -93,4 +94,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

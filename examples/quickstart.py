@@ -14,6 +14,8 @@ sys.path.insert(0, "src")
 
 import numpy as np
 
+from repro.runtime import enable_compile_cache
+
 from repro.core.distribution import build_scheme
 from repro.core.hooi import hooi
 from repro.core.metrics import scheme_metrics
@@ -51,4 +53,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -17,16 +17,14 @@ hit/miss counts, admission rejections and the routing decisions taken.
   PYTHONPATH=src python examples/serve_pool.py
 """
 
-import os
 import sys
 
 sys.path.insert(0, "src")
-# must be set before jax initializes; append so a user-provided XLA_FLAGS
-# keeps its other options
-if "xla_force_host_platform_device_count" not in os.environ.get("XLA_FLAGS", ""):
-    os.environ["XLA_FLAGS"] = (
-        os.environ.get("XLA_FLAGS", "")
-        + " --xla_force_host_platform_device_count=8").strip()
+from repro.runtime import enable_compile_cache, simulate_cpu_devices
+
+# on the CPU, 8 simulated host devices (set before jax initializes); the
+# pool needs 4 devices for its two P=2 lanes
+simulate_cpu_devices(8)
 
 import numpy as np
 
@@ -107,4 +105,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

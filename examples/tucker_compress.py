@@ -10,17 +10,14 @@ updated tables with host partitioning overlapped against device sweeps.
   PYTHONPATH=src python examples/tucker_compress.py
 """
 
-import os
 import sys
 
 sys.path.insert(0, "src")
-# 8 simulated host devices so the HooiExecutor section can run a real
-# distributed decomposition (must be set before jax initializes; append so
-# a user-provided XLA_FLAGS keeps its other options)
-if "xla_force_host_platform_device_count" not in os.environ.get("XLA_FLAGS", ""):
-    os.environ["XLA_FLAGS"] = (
-        os.environ.get("XLA_FLAGS", "")
-        + " --xla_force_host_platform_device_count=8").strip()
+from repro.runtime import enable_compile_cache, simulate_cpu_devices
+
+# on the CPU, 8 simulated host devices let the HooiExecutor section run a
+# real distributed decomposition; on a TPU the chips are the devices
+simulate_cpu_devices(8)
 
 import jax
 import numpy as np
@@ -170,4 +167,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -29,6 +29,8 @@ __all__ = [
     "dense_ttm",
     "dense_ttm_chain",
     "kron_contributions",
+    "fold_elements",
+    "ELEMENT_CHUNK",
     "penultimate",
     "penultimate_local",
     "core_from_factors",
@@ -90,6 +92,33 @@ def kron_contributions(
     return cur
 
 
+# Elements per chunk of ``fold_elements``. A whole-tensor contribution array
+# costs ~1 KB per element on the TPU (the (nnz, K) rows pad to 128 lanes)
+# and its XLA compile time grows linearly with nnz; a loop over fixed-size
+# chunks keeps both independent of nnz.
+ELEMENT_CHUNK = 1 << 16
+
+
+def fold_elements(step, acc, *arrays, chunk: int = ELEMENT_CHUNK):
+    """``acc = step(acc, *chunk_arrays)`` over consecutive element chunks.
+
+    ``arrays`` share their leading (element) dimension. Full chunks run in
+    a ``fori_loop`` and the remainder in one last call, so a tensor of at
+    most ``chunk`` elements is a single ``step`` on the whole arrays.
+    """
+    nnz = arrays[0].shape[0]
+    n_full = nnz // chunk if nnz > chunk else 0
+    if n_full:
+        def body(i, acc):
+            return step(acc, *(jax.lax.dynamic_slice_in_dim(a, i * chunk,
+                                                            chunk)
+                               for a in arrays))
+
+        acc = jax.lax.fori_loop(0, n_full, body, acc)
+        arrays = tuple(a[n_full * chunk:] for a in arrays)
+    return step(acc, *arrays)
+
+
 def penultimate(
     coords: jnp.ndarray,
     values: jnp.ndarray,
@@ -127,13 +156,19 @@ def core_from_factors(
 ) -> jnp.ndarray:
     """Core G = T x_1 F_1^T x_2 ... x_N F_N^T  (paper Fig 2 last step).
 
-    Computed element-wise: G = sum_e val(e) * outer(F_1[l_1], ..., F_N[l_N]).
-    Returns a (K_1, ..., K_N) tensor.
+    Computed element-wise: G = sum_e val(e) * outer(F_1[l_1], ..., F_N[l_N]),
+    in chunks of ``ELEMENT_CHUNK`` elements. Returns a (K_1, ..., K_N)
+    tensor.
     """
-    nnz = values.shape[0]
-    cur = values[:, None]
-    for j in range(len(factors)):
-        rows = jnp.take(factors[j], coords[:, j], axis=0)
-        cur = (cur[:, :, None] * rows[:, None, :]).reshape(nnz, -1)
-    core_flat = cur.sum(axis=0)
-    return core_flat.reshape(tuple(f.shape[1] for f in factors))
+    dims = tuple(int(f.shape[1]) for f in factors)
+
+    def chunk_sum(acc, coords, values):
+        cur = values[:, None]
+        for j in range(len(factors)):
+            rows = jnp.take(factors[j], coords[:, j], axis=0)
+            cur = (cur[:, :, None] * rows[:, None, :]).reshape(
+                values.shape[0], cur.shape[1] * rows.shape[1])
+        return acc + cur.sum(axis=0)
+
+    acc = jnp.zeros((int(np.prod(dims)),), jnp.result_type(values, *factors))
+    return fold_elements(chunk_sum, acc, coords, values).reshape(dims)

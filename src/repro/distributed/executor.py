@@ -49,7 +49,7 @@ from typing import Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core.coo import SparseTensor
 from repro.core.distribution import Scheme
@@ -81,7 +81,6 @@ from repro.engine import (
 )
 from repro.engine import zbuild as engine_zbuild
 from repro.engine.objective import resolve_objective
-from repro.jax_compat import make_mesh_auto, shard_map_compat
 from .partition import comm_model, make_mode_partition  # noqa: F401 — re-export
 
 __all__ = [
@@ -99,14 +98,18 @@ MAX_STOCH_UPLOADS = 32  # resident stochastic minibatches per executor
 RUN_PATHS = ("baseline", "liteopt", "auto")
 
 
-def make_ranks_mesh(P_ranks: int):
-    devs = jax.devices()
+def make_ranks_mesh(P_ranks: int, devices=None):
+    """1-D ``ranks`` mesh over ``devices`` (default: the first ``P_ranks``
+    of ``jax.devices()``), one rank per device."""
+    devs = jax.devices() if devices is None else list(devices)
     if len(devs) < P_ranks:
         raise ValueError(
-            f"need {P_ranks} devices, have {len(devs)} — set "
+            f"need {P_ranks} devices, have {len(devs)} — on the CPU set "
             "XLA_FLAGS=--xla_force_host_platform_device_count"
         )
-    return make_mesh_auto((P_ranks,), ("ranks",), devices=devs[:P_ranks])
+    return jax.make_mesh((P_ranks,), ("ranks",),
+                         axis_types=(jax.sharding.AxisType.Auto,),
+                         devices=devs[:P_ranks])
 
 
 # ------------------------------------------------------------------- stats
@@ -265,12 +268,12 @@ class HooiExecutor:
 
     # ------------------------------------------------------------ kernels
     def resolve_kernel(self, mp, core_dims: Sequence[int],
-                       use_kernel: bool | None) -> bool:
+                       use_kernel: bool | None, oracle_s: int = 0) -> bool:
         """Static kernel/fallback decision for one mode step's Z build
         (delegates to the engine's shared gate — see
         ``repro.engine.zbuild.resolve_kernel``)."""
         return engine_zbuild.resolve_kernel(mp.R_pad, core_dims, mp.mode,
-                                            use_kernel)
+                                            use_kernel, oracle_s)
 
     # ------------------------------------------------------------ planning
     def _check_plan(self, pl: PartitionPlan, t: SparseTensor,
@@ -362,7 +365,8 @@ class HooiExecutor:
                 backend=backend,
                 K_n=K_n,
                 niter=niter,
-                use_kernel=self.resolve_kernel(mp, eff, use_kernel),
+                use_kernel=self.resolve_kernel(
+                    mp, eff, use_kernel, s_eff if fz_n else 0),
                 precision=precision,
                 block_size=s_eff,
                 fused_zbuild=fz_n,
@@ -416,16 +420,16 @@ class HooiExecutor:
                 if path == "zbuild":
                     fn = make_zbuild_step_fn(ms, use_kernel,
                                              precision=precision)
-                    smap = shard_map_compat(
-                        fn, self.mesh,
+                    smap = jax.shard_map(
+                        fn, mesh=self.mesh, check_vma=False,
                         in_specs=(P("ranks"),) * 3 + (P(),),
                         out_specs=P("ranks"),
                     )
                 else:
                     backend = resolve_backend(path, self.P)
                     fn = make_mode_step_fn(ms, backend, K_n, niter)
-                    smap = shard_map_compat(
-                        fn, self.mesh,
+                    smap = jax.shard_map(
+                        fn, mesh=self.mesh, check_vma=False,
                         in_specs=(P("ranks"),) * 8 + (P(), P()),
                         out_specs=(P("ranks"), P()),
                     )
@@ -473,9 +477,11 @@ class HooiExecutor:
                 self._stats["upload_cache_hits"] += 1
                 tally["upload_cache_hits"] += 1
                 return up
-        # positional layout pinned by the engine's step functions
+        # positional layout pinned by the engine's step functions; each
+        # rank's slice of the leading axis goes to that rank's device
+        ranks = NamedSharding(self.mesh, P("ranks"))
         dev_args = tuple(
-            tuple(jnp.asarray(getattr(mp, f)) for f in ARRAY_FIELDS)
+            tuple(jax.device_put(getattr(mp, f), ranks) for f in ARRAY_FIELDS)
             for mp in pl.parts)
         row_perms = tuple(jnp.asarray(mp.row_perm) for mp in pl.parts)
         up = _PlanUpload(
@@ -552,6 +558,18 @@ class HooiExecutor:
         return pl, self.stage_upload(pl, t)
 
     # ------------------------------------------------------------ observe
+    def shard_devices(self, pl: PartitionPlan) -> dict:
+        """mode -> devices holding ranks 0..P-1's element arrays of ``pl``
+        (empty when the plan's arrays are not resident)."""
+        with self._lock:
+            up = self._uploads.get(pl)
+        if up is None:
+            return {}
+        return {n: [s.device for s in sorted(
+                    args[0].addressable_shards,
+                    key=lambda s: s.index[0].start or 0)]
+                for n, args in enumerate(up.dev_args)}
+
     def stats(self) -> dict:
         """Cumulative counters + cache occupancy."""
         with self._lock:

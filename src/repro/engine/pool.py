@@ -39,7 +39,6 @@ import dataclasses
 from typing import TYPE_CHECKING, Sequence
 
 from repro.engine.scheduler import StreamScheduler
-from repro.jax_compat import make_mesh_auto
 
 if TYPE_CHECKING:  # runtime import is deferred: executor imports repro.engine
     from repro.distributed.executor import HooiExecutor
@@ -137,14 +136,14 @@ class ExecutorPool:
         workers: int = 2,
         **scheduler_kw,
     ):
-        from repro.distributed.executor import HooiExecutor
+        from repro.distributed.executor import HooiExecutor, make_ranks_mesh
 
         self.P = int(P_ranks)
         self.core_dims = tuple(int(k) for k in core_dims)
         slices = device_slices(n_executors, P_ranks, devices)
         self.lanes: list[PoolLane] = []
         for i, sl in enumerate(slices):
-            mesh = make_mesh_auto((self.P,), ("ranks",), devices=sl)
+            mesh = make_ranks_mesh(self.P, devices=sl)
             ex = HooiExecutor(self.P, mesh=mesh)
             sched = StreamScheduler(ex, self.core_dims, lane=i,
                                     workers=workers, **scheduler_kw)

@@ -19,6 +19,7 @@ module is the only place the stages meet:
 
 from __future__ import annotations
 
+import functools
 from typing import Sequence
 
 import jax
@@ -43,9 +44,27 @@ ARRAY_FIELDS = ("coords", "values", "local_rows", "row_gid", "row_owned",
                 "bnd_slot", "own_bnd_slot", "own_bnd_off")
 
 
+def f32_matmuls(fn):
+    """Run (and trace) ``fn`` with f32 matmuls at full f32 precision.
+
+    The TPU's default contracts f32 operands in one bf16 pass (~3 decimal
+    digits), which would leave the Lanczos factors visibly non-orthonormal;
+    on the CPU this changes nothing. Dots that pin their own precision
+    (the Pallas kernels) keep it.
+    """
+
+    @functools.wraps(fn)
+    def wrapped(*args, **kwargs):
+        with jax.default_matmul_precision("float32"):
+            return fn(*args, **kwargs)
+
+    return wrapped
+
+
 def make_zbuild_step_fn(ms: dict, use_kernel: bool, precision: str = "f32"):
     """TTM-only step: just the local Z build (per-phase calibration probe)."""
 
+    @f32_matmuls
     def fn(coords, values, local_rows, factors):
         # shard_map keeps a leading size-1 'ranks' axis on sharded operands
         coords, values, local_rows = (
@@ -85,6 +104,7 @@ def make_mode_step_fn(ms: dict, backend: str, K_n: int, niter: int):
     assert not (fused_zbuild and warm_start == "sketch"), \
         "sketch warm start excludes the fused first product (spec builder)"
 
+    @f32_matmuls
     def fn(coords, values, local_rows, row_gid, row_owned, bnd_slot,
            own_bnd_slot, own_bnd_off, factors, key):
         (coords, values, local_rows, row_gid, row_owned, bnd_slot,
@@ -168,6 +188,7 @@ def make_stochastic_step_fn(mode: int, num_rows: int, K_n: int, niter: int,
     distributed step's refine-after-finalize discipline.
     """
 
+    @f32_matmuls
     def fn(coords, values, factors, key):
         Z = build_local_z(coords, values, coords[:, mode], factors, mode,
                           num_rows, use_kernel=use_kernel, sorted_rows=False,
@@ -186,6 +207,7 @@ def make_stochastic_step_fn(mode: int, num_rows: int, K_n: int, niter: int,
     return fn
 
 
+@f32_matmuls
 def local_mode_step(
     coords: jnp.ndarray,
     values: jnp.ndarray,

@@ -22,7 +22,7 @@ import jax
 import jax.numpy as jnp
 
 from repro import envknobs
-from repro.core.ttm import kron_contributions
+from repro.core.ttm import fold_elements, kron_contributions
 from repro.kernels import ops as kernel_ops
 
 __all__ = ["build_local_z", "build_local_z_oracle", "resolve_kernel",
@@ -80,18 +80,20 @@ def resolve_fused_zbuild(fused_zbuild: bool | None) -> bool:
 
 
 def resolve_kernel(num_rows: int, core_dims: Sequence[int], mode: int,
-                   use_kernel: bool | None) -> bool:
+                   use_kernel: bool | None, oracle_s: int = 0) -> bool:
     """Static kernel/reference decision for one mode step's Z build.
 
     ``True`` forces the kernel wherever the VMEM gate admits the shape
     (differential tests); ``False`` pins the jnp ``segment_sum`` reference;
-    ``None`` is the auto policy described in the module docstring. The
-    resolved choice must be part of any compiled-step cache key.
+    ``None`` is the auto policy described in the module docstring.
+    ``oracle_s`` is the fused first-product panel width (0 = plain build),
+    which the fused kernel's footprint includes. The resolved choice must
+    be part of any compiled-step cache key.
     """
     if use_kernel is False:
         return False
     Ka, Kb = kernel_ops.split_kron_dims(core_dims, mode)
-    fits = kernel_ops.kernel_fits_vmem(num_rows, Ka, Kb)
+    fits = kernel_ops.kernel_fits_vmem(num_rows, Ka, Kb, oracle_s=oracle_s)
     if use_kernel is None:
         return fits and (jax.default_backend() == "tpu"
                          or kernel_forced_by_env())
@@ -125,10 +127,20 @@ def build_local_z(
               else kernel_ops.penultimate_local)
         return fn(coords, values, local_rows, factors, mode, num_rows,
                   use_kernel=True, precision=precision)
-    contribs = kron_contributions(coords, values, factors, mode)
-    if precision == "bf16":
-        contribs = contribs.astype(jnp.bfloat16).astype(jnp.float32)
-    return jax.ops.segment_sum(contribs, local_rows, num_segments=num_rows)
+
+    def chunk_z(acc, coords, values, rows):
+        contribs = kron_contributions(coords, values, factors, mode)
+        if precision == "bf16":
+            contribs = contribs.astype(jnp.bfloat16).astype(jnp.float32)
+        return acc + jax.ops.segment_sum(contribs, rows,
+                                         num_segments=num_rows)
+
+    khat = 1
+    for j, f in enumerate(factors):
+        if j != mode:
+            khat *= int(f.shape[1])
+    acc = jnp.zeros((num_rows, khat), jnp.result_type(values, *factors))
+    return fold_elements(chunk_z, acc, coords, values, local_rows)
 
 
 def build_local_z_oracle(

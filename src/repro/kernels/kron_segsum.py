@@ -28,13 +28,15 @@ each element block its first row-block so only ``span`` row windows are
 updated per step (total MXU work ≈ span·128/block_e ≈ 1.5x the minimal
 E·K̂ MACs, versus a fully dense one-hot matmul's R_pad/128 x blowup).
 
-VMEM budget per step: Z tile (R_pad·Ka·Kb_blk·4B) + C (block_e·Ka·Kb_blk·4B)
-+ inputs; ops.py enforces <= ~12 MiB and falls back to the jnp reference
-beyond that (large-R cases are sharded across devices anyway — Lite's
-R_max <= ceil(L/P)+2 bound is precisely what keeps R_pad small per device).
+VMEM per launch: the double-buffered Z tile plus element-block temporaries
+(``TileGeometry.vmem_bytes``); ops.py admits launches within its budget and
+falls back to the jnp reference beyond that (large-R cases are sharded
+across devices anyway — Lite's R_max <= ceil(L/P)+2 bound is precisely what
+keeps R_pad small per device), and the kernel asks Mosaic for a matching
+scoped-VMEM limit.
 
 Validated against ref.kron_segsum_ref in interpret mode (CPU) across shape/
-dtype sweeps; targets TPU via pl.pallas_call + BlockSpec VMEM tiling.
+dtype sweeps, and compiled for a described v5e in tests/test_tpu_compile.py.
 """
 
 from __future__ import annotations
@@ -47,12 +49,13 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .pallas_compat import tpu_compiler_params
 
 __all__ = ["kron_segsum", "kron_segsum_oracle", "tile_geometry",
            "TileGeometry", "ROW_BLOCK"]
 
 ROW_BLOCK = 128
+LANE = 128  # minor-dimension tile: narrower blocks pad up to it in VMEM
+DEFAULT_SCOPED_VMEM = 16 * 1024 * 1024  # Mosaic's limit when none is given
 
 
 @dataclasses.dataclass(frozen=True)
@@ -75,17 +78,40 @@ class TileGeometry:
 
     @property
     def vmem_bytes(self) -> int:
-        """Resident bytes per grid step: Z tile (always f32) + C block at
-        the contribution itemsize, plus — when the first oracle product is
-        fused in — the resident X panel slab and the (R_pad, s) accumulator.
+        """VMEM one launch allocates, as Mosaic lays it out:
+
+        * the f32 Z tile — a pipelined output block, so double-buffered;
+        * the element block: its double-buffered inputs (rows, a, b, each
+          padded to 128 lanes), the f32 kron product, C at the
+          contribution itemsize, and three f32 (128, Ka*kb_blk) row-window
+          temporaries (the one-hot update, the Z read and their sum);
+        * with the fused oracle: the double-buffered X slab and (R_pad, s)
+          accumulator (s padded to 128 lanes) and one row block's product.
+
+        For the nell2-s mode-0 launch (R_pad 1792, Ka 10, kb_blk 128) this
+        is 22.6 MiB; a v5e compile allocated 22.3 MiB.
         """
-        z_tile = self.R_pad * self.Ka * self.kb_blk * 4
-        c_blk = self.block_e * self.Ka * self.kb_blk * self.itemsize
+        width = self.Ka * self.kb_blk
+        z_tile = 2 * self.R_pad * width * 4
+        inputs = 2 * self.block_e * (LANE + _lanes(self.Ka) + self.kb_blk) * 4
+        elems = (self.block_e * width * (4 + self.itemsize)
+                 + 3 * ROW_BLOCK * width * 4)
         oracle = 0
         if self.oracle_s:
-            oracle = (self.Ka * self.kb_blk * self.oracle_s * 4
-                      + self.R_pad * self.oracle_s * 4)
-        return z_tile + c_blk + oracle
+            s = _lanes(self.oracle_s)
+            oracle = (2 * width * s * 4 + 2 * self.R_pad * s * 4
+                      + ROW_BLOCK * (width + s) * 4)
+        return z_tile + inputs + elems + oracle
+
+    @property
+    def vmem_limit_bytes(self) -> int:
+        """Scoped-VMEM limit asked of Mosaic: the footprint with a quarter
+        of headroom, never below Mosaic's own default."""
+        return max(DEFAULT_SCOPED_VMEM, self.vmem_bytes * 5 // 4)
+
+
+def _lanes(n: int) -> int:
+    return -(-n // LANE) * LANE
 
 
 def tile_geometry(num_rows: int, Ka: int, Kb: int,
@@ -106,6 +132,28 @@ def tile_geometry(num_rows: int, Ka: int, Kb: int,
     )
 
 
+def _mxu_precision(dtype):
+    """f32 operands contract at full f32 precision (the MXU default is one
+    bf16 pass); bf16 operands, the opt-in mixed-precision contract, at the
+    default — Mosaic refuses an fp32 contraction of bf16 operands."""
+    return (jax.lax.Precision.HIGHEST if dtype == jnp.float32
+            else jax.lax.Precision.DEFAULT)
+
+
+def _contrib_block(a_ref, b_ref, block_e: int, Ka: int, kb_blk: int):
+    """C[e, ka*kb_blk + kb] = a[e, ka] * b[e, kb]  (C-order kron).
+
+    Built in f32 and rounded to the operands' dtype afterwards: Mosaic
+    refuses the (block_e, Ka, kb_blk) -> (block_e, Ka*kb_blk) shape cast
+    on packed bf16 vectors. A product of two bf16 values is exact in f32,
+    so the rounded C equals the bf16 product.
+    """
+    a = a_ref[...].astype(jnp.float32)  # (block_e, Ka)
+    b = b_ref[...].astype(jnp.float32)  # (block_e, kb_blk)
+    C = (a[:, :, None] * b[:, None, :]).reshape(block_e, Ka * kb_blk)
+    return C.astype(a_ref.dtype)
+
+
 def _kernel(first_rb_ref, rows_ref, a_ref, b_ref, z_ref, *, span: int,
             block_e: int, Ka: int, kb_blk: int):
     k = pl.program_id(0)  # Kb-block index (outer)
@@ -116,11 +164,8 @@ def _kernel(first_rb_ref, rows_ref, a_ref, b_ref, z_ref, *, span: int,
     def _init():
         z_ref[...] = jnp.zeros_like(z_ref)
 
-    a = a_ref[...]  # (block_e, Ka)
-    b = b_ref[...]  # (block_e, kb_blk)
     rows = rows_ref[...]  # (block_e, 1) int32, sorted, dense ids
-    # C[e, ka*kb_blk + kb] = a[e, ka] * b[e, kb]   (C-order kron)
-    C = (a[:, :, None] * b[:, None, :]).reshape(block_e, Ka * kb_blk)
+    C = _contrib_block(a_ref, b_ref, block_e, Ka, kb_blk)
 
     row0 = first_rb_ref[i] * ROW_BLOCK
     local = rows[:, 0] - row0  # (block_e,) in [0, span*128) for real elements
@@ -129,12 +174,12 @@ def _kernel(first_rb_ref, rows_ref, a_ref, b_ref, z_ref, *, span: int,
         onehot = (local[:, None] == col + s * ROW_BLOCK).astype(C.dtype)
         upd = jax.lax.dot_general(
             onehot, C, (((0,), (0,)), ((), ())),
+            precision=_mxu_precision(C.dtype),
             preferred_element_type=jnp.float32,
         )  # (128, Ka*kb_blk) on the MXU
         idx = (pl.dslice(row0 + s * ROW_BLOCK, ROW_BLOCK),
                slice(None), slice(None))
-        cur = pl.load(z_ref, idx)
-        pl.store(z_ref, idx, cur + upd.reshape(ROW_BLOCK, Ka, kb_blk))
+        z_ref[idx] = z_ref[idx] + upd.reshape(ROW_BLOCK, Ka, kb_blk)
 
 
 def _cast_contrib_operands(a, b, precision):
@@ -220,7 +265,9 @@ def kron_segsum(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((R_pad, Ka, Kb_pad), jnp.float32),
         interpret=interpret,
-        compiler_params=tpu_compiler_params(("arbitrary", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=geom.vmem_limit_bytes),
     )(first_rb.astype(jnp.int32), rows[:, None].astype(jnp.int32), a, b)
     return z3[:num_rows, :, :Kb].reshape(num_rows, Ka * Kb)
 
@@ -248,10 +295,8 @@ def _kernel_fused(first_rb_ref, rows_ref, a_ref, b_ref, x_ref, z_ref, zx_ref,
     def _init_zx():
         zx_ref[...] = jnp.zeros_like(zx_ref)
 
-    a = a_ref[...]  # (block_e, Ka)
-    b = b_ref[...]  # (block_e, kb_blk)
     rows = rows_ref[...]  # (block_e, 1) int32, sorted, dense ids
-    C = (a[:, :, None] * b[:, None, :]).reshape(block_e, Ka * kb_blk)
+    C = _contrib_block(a_ref, b_ref, block_e, Ka, kb_blk)
 
     row0 = first_rb_ref[i] * ROW_BLOCK
     local = rows[:, 0] - row0
@@ -260,25 +305,33 @@ def _kernel_fused(first_rb_ref, rows_ref, a_ref, b_ref, x_ref, z_ref, zx_ref,
         onehot = (local[:, None] == col + s * ROW_BLOCK).astype(C.dtype)
         upd = jax.lax.dot_general(
             onehot, C, (((0,), (0,)), ((), ())),
+            precision=_mxu_precision(C.dtype),
             preferred_element_type=jnp.float32,
         )
         idx = (pl.dslice(row0 + s * ROW_BLOCK, ROW_BLOCK),
                slice(None), slice(None))
-        cur = pl.load(z_ref, idx)
-        pl.store(z_ref, idx, cur + upd.reshape(ROW_BLOCK, Ka, kb_blk))
+        z_ref[idx] = z_ref[idx] + upd.reshape(ROW_BLOCK, Ka, kb_blk)
 
     @pl.when(i == n_eb - 1)
     def _oracle():
         # Z tile for Kb block k is final here; contract it with the matching
-        # X slab while it is still resident. kb_blk is a multiple of 128, so
-        # the (R_pad, Ka, kb_blk) -> (R_pad, Ka*kb_blk) reshape is
-        # layout-preserving.
-        Zf = z_ref[...].reshape(R_pad, Ka * kb_blk)
+        # X slab while it is still resident, one 128-row block at a time (a
+        # whole-tile value would be one more tile of VMEM). kb_blk is a
+        # multiple of 128, so the (128, Ka, kb_blk) -> (128, Ka*kb_blk)
+        # reshape is layout-preserving.
         Xf = x_ref[...].reshape(Ka * kb_blk, x_ref.shape[-1])
-        zx_ref[...] += jax.lax.dot_general(
-            Zf, Xf, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+
+        def row_block(r, carry):
+            rows = pl.ds(pl.multiple_of(r * ROW_BLOCK, ROW_BLOCK), ROW_BLOCK)
+            Zb = z_ref[rows, :, :].reshape(ROW_BLOCK, Ka * kb_blk)
+            zx_ref[rows, :] += jax.lax.dot_general(
+                Zb, Xf, (((1,), (0,)), ((), ())),
+                precision=jax.lax.Precision.HIGHEST,
+                preferred_element_type=jnp.float32,
+            )
+            return carry
+
+        jax.lax.fori_loop(0, R_pad // ROW_BLOCK, row_block, 0)
 
 
 @functools.partial(
@@ -361,7 +414,9 @@ def kron_segsum_oracle(
             jax.ShapeDtypeStruct((R_pad, s), jnp.float32),
         ],
         interpret=interpret,
-        compiler_params=tpu_compiler_params(("arbitrary", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=geom.vmem_limit_bytes),
     )(first_rb.astype(jnp.int32), rows[:, None].astype(jnp.int32), a, b, X3)
     return (z3[:num_rows, :, :Kb].reshape(num_rows, Ka * Kb),
             zx[:num_rows, :])

@@ -30,19 +30,19 @@ __all__ = ["penultimate", "penultimate_local", "penultimate_sorted",
            "penultimate_sorted_oracle", "oracle_pair", "kernel_fits_vmem",
            "split_kron_dims", "vmem_budget_bytes"]
 
-# conservative default VMEM budget for the resident Z tile + C block (bytes);
-# override per-platform with REPRO_VMEM_BUDGET or the vmem_budget_bytes
-# parameter on the gate
-_VMEM_BUDGET = 12 * 1024 * 1024
+# default admission budget for one launch's VMEM footprint
+# (``TileGeometry.vmem_bytes``), in bytes: a quarter of a v5e core's
+# 128 MiB. Override per platform with REPRO_VMEM_BUDGET or the
+# vmem_budget parameter on the gate.
+_VMEM_BUDGET = 32 * 1024 * 1024
 
 
 def vmem_budget_bytes() -> int:
     """The admission budget for resident kernel tiles, in bytes.
 
     ``REPRO_VMEM_BUDGET`` (bytes; parsed and validated by
-    ``repro.envknobs``) overrides the conservative default, so a real-TPU
-    deployment can open up the full ~16 MiB/core (or a fraction, leaving
-    headroom for double buffering) without a code change.
+    ``repro.envknobs``) overrides the default, so a TPU with less (or
+    more) VMEM per core than a v5e can be served without a code change.
     """
     from repro import envknobs
 

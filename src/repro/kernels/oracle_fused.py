@@ -24,9 +24,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu  # noqa: F401 — kernels reference pltpu types
-
-from .pallas_compat import tpu_compiler_params
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["oracle_pair"]
 
@@ -39,10 +37,12 @@ def _kernel(z_ref, x_ref, y_ref, xo_ref, yo_ref):
     x = x_ref[...]  # (Khat, s) — s = panel width (1 for the vector oracle)
     y = y_ref[...]  # (128, s)
     xo_ref[...] = jax.lax.dot_general(
-        Z, x, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        Z, x, (((1,), (0,)), ((), ())), precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32,
     )  # (128, s)
     zty = jax.lax.dot_general(
-        Z, y, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        Z, y, (((0,), (0,)), ((), ())), precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32,
     )  # (Khat, s)
 
     @pl.when(i == 0)
@@ -97,7 +97,8 @@ def oracle_pair(
             jax.ShapeDtypeStruct((K_pad, s), jnp.float32),
         ],
         interpret=interpret,
-        compiler_params=tpu_compiler_params(("arbitrary",)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
     )(Zp, xp, yp)
     if vec_in:
         return xo[:R, 0], yo[:Khat, 0]

@@ -86,3 +86,31 @@ def test_run_benches_detects_stray_artifacts(tmp_path, monkeypatch):
 
     paths = benchrun.run_benches([bench_clean], out_dir=str(out))
     assert len(paths) == 1
+
+
+def test_bench_environment_raises_without_a_device(monkeypatch):
+    """An artifact must never claim a device it did not see."""
+    import jax
+
+    def no_devices():
+        raise RuntimeError("no backend")
+
+    monkeypatch.setattr(jax, "devices", no_devices)
+    with pytest.raises(RuntimeError, match="no backend"):
+        benchrun.bench_environment()
+
+
+def test_child_benches_run_before_in_process_ones(tmp_path):
+    """A bench that computes with JAX here would hold the accelerator a
+    child process needs, so every child bench runs first."""
+    order = []
+
+    def bench_here():
+        order.append("here")
+
+    @benchrun._in_child
+    def bench_child():
+        order.append("child")
+
+    benchrun.run_benches([bench_here, bench_child], out_dir=str(tmp_path))
+    assert order == ["child", "here"]

@@ -31,6 +31,49 @@ def _restore_cost_model():
     set_cost_model(None)
 
 
+# ------------------------------------------------ Z-build seam (chunked)
+def test_fold_elements_matches_one_pass():
+    import jax.numpy as jnp
+
+    from repro.core.ttm import fold_elements
+
+    x = jnp.arange(23.0)
+
+    def step(acc, v):
+        return acc + v.sum()
+
+    assert float(fold_elements(step, 0.0, x, chunk=100)) == float(x.sum())
+    assert float(fold_elements(step, 0.0, x, chunk=5)) == float(x.sum())
+
+
+def test_chunked_reference_z_and_core_match_whole_tensor():
+    """Past ``ELEMENT_CHUNK`` elements the reference Z build and the core
+    accumulate chunk by chunk; both still equal the whole-tensor sums."""
+    import jax.numpy as jnp
+
+    from repro.core.ttm import ELEMENT_CHUNK, core_from_factors, penultimate
+    from repro.engine.zbuild import build_local_z
+
+    rng = np.random.default_rng(0)
+    shape, nnz = (40, 30, 20), ELEMENT_CHUNK + 1234
+    c = np.stack([rng.integers(0, L, nnz) for L in shape], axis=1)
+    v = rng.standard_normal(nnz)
+    F = [rng.standard_normal((L, 3)) for L in shape]
+    coords = jnp.asarray(c, jnp.int32)
+    values = jnp.asarray(v, jnp.float32)
+    factors = [jnp.asarray(f, jnp.float32) for f in F]
+
+    Z = build_local_z(coords, values, coords[:, 1], factors, 1, shape[1],
+                      sorted_rows=False)
+    np.testing.assert_allclose(
+        Z, penultimate(coords, values, factors, 1, shape[1]),
+        rtol=1e-4, atol=1e-3)
+    G_ref = np.einsum("e,ea,eb,ec->abc", v, F[0][c[:, 0]], F[1][c[:, 1]],
+                      F[2][c[:, 2]])
+    np.testing.assert_allclose(core_from_factors(coords, values, factors),
+                               G_ref, rtol=1e-4, atol=1e-2)
+
+
 # --------------------------------------------------- oracle seam (fused)
 def test_fused_oracle_matches_svd_via_lanczos():
     """The Pallas oracle_pair kernel, wired through the oracle seam, must

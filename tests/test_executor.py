@@ -49,6 +49,20 @@ def test_second_run_zero_compilations_zero_uploads(executor, lowrank_tensor):
 
 
 @pytest.mark.slow
+def test_rank_arrays_sit_on_their_rank_devices(executor, lowrank_tensor):
+    """Each rank's slice of a resident plan is uploaded to that rank's
+    device of the mesh, not left on one device for the step to reshard."""
+    t = lowrank_tensor
+    pl = plan(t, "lite", 4, core_dims=(2, 2, 2))
+    assert executor.shard_devices(pl) == {}
+    executor.run(t, (2, 2, 2), pl, n_invocations=1, seed=0)
+    placed = executor.shard_devices(pl)
+    assert sorted(placed) == list(range(t.ndim))
+    for devs in placed.values():
+        assert devs == list(executor.mesh.devices.flat)
+
+
+@pytest.mark.slow
 def test_identical_padded_shapes_share_compiled_steps(executor,
                                                       lowrank_tensor):
     """Multi-tensor batching: a second tensor whose partitions pad to the
