@@ -1,0 +1,5 @@
+"""hbm_peak_gb: ``peak_bytes_in_use`` of the fullest device after the window, in GB."""
+
+
+def read(ctx):
+    return ctx["memory_peak_bytes"] / 1e9
