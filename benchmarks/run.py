@@ -9,7 +9,6 @@ upload the perf trajectory as artifacts. Output dir: ``--out-dir`` or the
 Mapping (see DESIGN.md §7):
   Fig 9   bench_dataset_suite       tensor stats of the synthetic mirror suite
   Fig10/14 bench_hooi_time          HOOI wall-time x scheme (8 simulated ranks)
-  Fig 11  bench_time_breakup        TTM vs SVD vs comm time x scheme
   Fig 12  bench_metrics             E^max/R^sum/R^max (imbalance + redundancy)
   Fig 13  bench_comm_volume         SVD vs factor-matrix volumes x scheme
   Fig 15  bench_scaling             critical-path scaling P=4..64
@@ -97,7 +96,7 @@ def bench_dataset_suite() -> None:
              f"sparsity={t.sparsity:.2e}")
 
 
-# ------------------------------------------------------------ Fig 10/14/11
+# --------------------------------------------------------------- Fig 10/14
 _DIST_BENCH_BODY = """
     import json, time
     import numpy as np
@@ -189,34 +188,6 @@ def bench_hooi_time() -> None:
                  f"fit={rec['fit']:.4f};ran={rec['ran']};"
                  f"warm_cache_hit={rec['cache_hit']};"
                  f"objective={rec['objective']};backends={rec['backends']}")
-
-
-def bench_time_breakup() -> None:
-    """Single-rank HOOI instrumented into TTM vs SVD phases (Fig 11's
-    computation-dominance claim), plus the analytic comm model."""
-    from repro.core.hooi import hooi_invocation, random_factors
-    from repro.core.distribution import build_scheme
-    from repro.distributed.dist_hooi import comm_model
-    from repro.distributed.partition import make_mode_partition
-    import jax
-
-    suite = _suite(scale=0.12)
-    for tname in ("delicious-s", "nell2-s"):
-        t = suite[tname]
-        core = (10,) * t.ndim
-        factors = random_factors(t.shape, core, jax.random.PRNGKey(0))
-        timings: dict = {}
-        hooi_invocation(t, factors, jax.random.PRNGKey(1), timings=timings)
-        timings2: dict = {}
-        hooi_invocation(t, factors, jax.random.PRNGKey(1), timings=timings2)
-        total = timings2["ttm"] + timings2["svd"]
-        scheme = build_scheme(t, "lite", 8)
-        khat = int(np.prod(core[1:]))
-        comm = comm_model(make_mode_partition(t, scheme, 0), khat, 2 * core[0])
-        _row(f"fig11/{tname}", total * 1e6,
-             f"ttm_frac={timings2['ttm']/total:.2f};"
-             f"svd_frac={timings2['svd']/total:.2f};"
-             f"liteopt_comm_bytes={comm['liteopt_bytes']:.0f}")
 
 
 # ----------------------------------------------------------------- Fig 12
@@ -1260,7 +1231,6 @@ BENCHES = [
     bench_scaling,
     bench_distribution_time,
     bench_memory,
-    bench_time_breakup,
     bench_kernel_oracle,
     bench_kernel_ttm,
     bench_kernel_roofline,

@@ -99,7 +99,7 @@ def main() -> None:
         print(f"  decisions={st.decisions}")
         for ls in st.lane_stats:
             print(f"  lane: completed={ls['completed']}  "
-                  f"host_s={ls['host_s']:.2f}  device_s={ls['device_s']:.2f}  "
+                  f"host_s={ls['host_s']:.2f}  run_s={ls['run_s']:.2f}  "
                   f"queue_wait_s={ls['queue_wait_s']:.2f}")
         router.close()
 

@@ -162,7 +162,7 @@ def main() -> None:
                   f"hot_path_uploads={r.stats.uploads}")
         st = sched.stats()
     print(f"[stream] pipeline: wall={st['wall_s']:.2f}s vs "
-          f"host {st['host_s']:.2f}s + device {st['device_s']:.2f}s "
+          f"host {st['host_s']:.2f}s + run {st['run_s']:.2f}s "
           f"(overlap hid {st['overlap_s']:.2f}s); decisions={st['decisions']}")
 
 

@@ -72,7 +72,6 @@ def hooi_invocation(
     key: jax.Array,
     lanczos_iters: int | None = None,
     use_kernels: bool = False,
-    timings: dict | None = None,
     use_fused_oracle: bool | None = None,
     precision: str | None = None,
     lanczos_block: int | None = None,
@@ -82,8 +81,8 @@ def hooi_invocation(
 ) -> list[jnp.ndarray]:
     """One HOOI invocation: refine all factor matrices (no core update).
 
-    Thin wrapper over the engine's local mode step (kept for direct callers
-    and the phase-instrumentation benchmarks; per-mode keys are derived as
+    Thin wrapper over the engine's local mode step (kept for direct
+    callers; per-mode keys are derived as
     ``fold_in(key, n)``, the historical convention for this entry point).
     ``objective`` is an already-resolved ``engine.objective.Objective`` (or
     None for the standard Tucker behavior); this entry point does not apply
@@ -103,7 +102,6 @@ def hooi_invocation(
     fz = resolve_fused_zbuild(fused_zbuild)
     warm = resolve_warm_start(warm_start)
     new_factors = list(factors)
-    track = timings if timings is not None else {}
     for n in range(t.ndim):
         k_n = int(new_factors[n].shape[1])
         khat = 1
@@ -124,7 +122,7 @@ def hooi_invocation(
             niter=niter, use_kernel=use_kernels,
             use_fused_oracle=bool(use_fused_oracle), precision=prec,
             block_size=s_eff, fused_zbuild=fz_n, warm_start=ws_n,
-            timings=track, objective=objective,
+            objective=objective,
         )
     return new_factors
 

@@ -89,7 +89,12 @@ def block_start_panel(key: jax.Array, ncols: int, block_size: int) -> jnp.ndarra
 def _space_reduce(axis: str | None) -> Callable[[jnp.ndarray], jnp.ndarray]:
     if axis is None:
         return lambda x: x
-    return lambda x: jax.lax.psum(x, axis)
+
+    def reduce(x):  # u-space inner products across the mesh
+        with jax.named_scope("comm"):
+            return jax.lax.psum(x, axis)
+
+    return reduce
 
 
 def gk_bidiag(

@@ -36,10 +36,11 @@ from __future__ import annotations
 import dataclasses
 import json
 import threading
-import time
 from typing import Sequence
 
 import numpy as np
+
+from repro.tracing import Tally, span
 
 from .calibrate import current_cost_model_state
 from .coo import SparseTensor
@@ -113,7 +114,7 @@ class PartitionPlan:
     cost: PlanCost
     core_dims: tuple[int, ...]
     P: int
-    build_s: float  # measured host-side construction wall time
+    build_s: float  # host wall time of the ``plan`` span that built it
     cache_key: tuple | None = None
     # auto only: modeled total_s per candidate name (selection transparency)
     candidates: dict | None = None
@@ -135,6 +136,11 @@ class PartitionPlan:
     # extra FLOP terms — running it under another objective would be wrong
     # twice, so executors and load() refuse a mismatch
     objective: str = "tucker"
+    # span name -> [count, host seconds] of the build: ``plan`` and its
+    # children ``plan.fingerprint``, ``plan.scheme`` (the distribution
+    # scheme), ``plan.partition``, ``plan.metrics``, ``plan.cost``; None
+    # for a plan loaded from a file
+    build_spans: dict | None = None
 
     @property
     def name(self) -> str:
@@ -395,7 +401,6 @@ def _build_plan(
     scheme: Scheme,
     core_dims: tuple[int, ...],
     path: str,
-    build_s: float,
     cache_key: tuple | None,
     model,
     pad_geometric: bool = False,
@@ -404,12 +409,14 @@ def _build_plan(
 ) -> PartitionPlan:
     from repro.distributed.partition import make_mode_partitions
 
-    t0 = time.perf_counter()
-    parts = make_mode_partitions(t, scheme, pad_geometric=pad_geometric)
+    with span("plan.partition"):
+        parts = make_mode_partitions(t, scheme, pad_geometric=pad_geometric)
     if metrics is None:
-        metrics = scheme_metrics(t, scheme, core_dims)
-    cost = _plan_cost(parts, metrics, core_dims, path, model,
-                      objective=objective)
+        with span("plan.metrics"):
+            metrics = scheme_metrics(t, scheme, core_dims)
+    with span("plan.cost"):
+        cost = _plan_cost(parts, metrics, core_dims, path, model,
+                          objective=objective)
     return PartitionPlan(
         scheme=scheme,
         parts=parts,
@@ -417,7 +424,7 @@ def _build_plan(
         cost=cost,
         core_dims=core_dims,
         P=scheme.P,
-        build_s=build_s + (time.perf_counter() - t0),
+        build_s=0.0,  # plan() sets it when the build's span closes
         cache_key=cache_key,
         fingerprint=t.fingerprint(),
         stream_version=getattr(t, "_stream_version", None),
@@ -467,7 +474,27 @@ def plan(
     ``SchemeMetrics``, skipping the O(nnz·N²) recompute — the streaming
     scheduler maintains them incrementally across appends
     (``repro.core.metrics.MetricsExtender``).
+
+    The call runs under the span ``plan`` (``repro.tracing``). A plan built
+    by the call carries the span's host seconds as ``build_s`` and its
+    children's as ``build_spans``; a cache hit returns the cached plan with
+    the numbers of its own build.
     """
+    with Tally() as spans, span("plan") as sp:
+        pl = _plan(t, scheme, P, core_dims=core_dims, path=path, seed=seed,
+                   use_cache=use_cache, pad_geometric=pad_geometric,
+                   objective=objective, metrics=metrics, **scheme_kw)
+    if not last_plan_call_cache_hit():
+        # built by this call (frozen: set once, before anyone reads it)
+        object.__setattr__(pl, "build_s", sp.seconds)
+        object.__setattr__(pl, "build_spans", spans.spans)
+    return pl
+
+
+def _plan(t: SparseTensor, scheme: str | Scheme, P: int | None, *,
+          core_dims: Sequence[int] | None, path: str, seed: int,
+          use_cache: bool, pad_geometric: bool, objective,
+          metrics: SchemeMetrics | None, **scheme_kw) -> PartitionPlan:
     if path not in ("baseline", "liteopt", "auto"):
         raise ValueError(f"unknown path {path!r}")
     from repro.engine.objective import resolve_objective
@@ -482,6 +509,8 @@ def plan(
     # plans scored under the old rates (model and version read in one
     # snapshot, so the cached cost always matches its key's version)
     model, mv = current_cost_model_state()
+    with span("plan.fingerprint"):
+        fp = t.fingerprint()
 
     if isinstance(scheme, Scheme):
         if P is not None and P != scheme.P:
@@ -489,10 +518,12 @@ def plan(
         # key on scheme *content*, never id(): a GC'd scheme's id can be
         # reused by CPython, which would hand a different scheme the old
         # plan; equal-content schemes sharing one cached plan is correct
-        key = ("prebuilt", scheme.content_key(), t.fingerprint(), core, path,
-               mv, pad_geometric, obj.cache_token())
+        with span("plan.fingerprint"):
+            scheme_key = scheme.content_key()
+        key = ("prebuilt", scheme_key, fp, core, path, mv, pad_geometric,
+               obj.cache_token())
         return _cached(key, use_cache,
-                       lambda: _build_plan(t, scheme, core, path, 0.0, key,
+                       lambda: _build_plan(t, scheme, core, path, key,
                                            model, pad_geometric,
                                            objective=obj, metrics=metrics))
     if metrics is not None:
@@ -502,12 +533,11 @@ def plan(
     P = 8 if P is None else int(P)
 
     name = scheme.lower()
-    key = (t.fingerprint(), name, P, core, path, seed, _freeze_kw(scheme_kw),
-           mv, pad_geometric, obj.cache_token())
+    key = (fp, name, P, core, path, seed, _freeze_kw(scheme_kw), mv,
+           pad_geometric, obj.cache_token())
 
     if name == "auto":
         def make_auto() -> PartitionPlan:
-            t0 = time.perf_counter()
             cands = {
                 c: plan(t, c, P, core_dims=core, path=path, seed=seed,
                         use_cache=use_cache, pad_geometric=pad_geometric,
@@ -518,17 +548,16 @@ def plan(
             return dataclasses.replace(
                 cands[best],
                 cache_key=key,
-                build_s=time.perf_counter() - t0,
                 candidates={c: p.cost.total_s for c, p in cands.items()},
             )
 
         return _cached(key, use_cache, make_auto)
 
     def make() -> PartitionPlan:
-        t0 = time.perf_counter()
-        s = build_scheme(t, name, P, seed=seed, **scheme_kw)
-        return _build_plan(t, s, core, path, time.perf_counter() - t0, key,
-                           model, pad_geometric, objective=obj)
+        with span("plan.scheme"):
+            s = build_scheme(t, name, P, seed=seed, **scheme_kw)
+        return _build_plan(t, s, core, path, key, model, pad_geometric,
+                           objective=obj)
 
     return _cached(key, use_cache, make)
 

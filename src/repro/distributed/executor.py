@@ -41,6 +41,7 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import functools
 import threading
 import time
 import weakref
@@ -81,6 +82,7 @@ from repro.engine import (
 )
 from repro.engine import zbuild as engine_zbuild
 from repro.engine.objective import resolve_objective
+from repro.tracing import Tally, hlo_scopes, span
 from .partition import comm_model, make_mode_partition  # noqa: F401 — re-export
 
 __all__ = [
@@ -191,6 +193,13 @@ class DistHooiStats:
     # scheduler-filled: final fit minus the last *full* run's final fit —
     # the rung's observable fit error, bounded by the correction sweep
     fit_delta: float | None = None
+    # ---- tracing (repro.tracing) ----
+    # span name -> [count, host seconds] of every span this call closed
+    # (hooi.run, hooi.plan, hooi.upload, hooi.sweep, hooi.step, ...)
+    spans: dict | None = None
+    # innermost open span -> XLA compilations this call (hooi.step: a new
+    # step executable; hooi.core: the core recompiled)
+    compiles: dict | None = None
 
 
 @dataclasses.dataclass
@@ -223,6 +232,21 @@ class _ModeSpec:
     warm_start: str = "none"  # resolved oracle warm start ("none"|"sketch")
 
 
+def _traced_call(run):
+    """Run an executor entry point as one call: under the span ``hooi.run``
+    (``call`` = the executor's run count before it) with a ``Tally`` whose
+    spans and compilations land on the returned ``DistHooiStats``."""
+
+    @functools.wraps(run)
+    def traced(self, *args, **kwargs):
+        with Tally() as tally, span("hooi.run", call=self._stats["runs"]):
+            dec, stats = run(self, *args, **kwargs)
+        stats.spans, stats.compiles = tally.spans, tally.compiles
+        return dec, stats
+
+    return traced
+
+
 # ---------------------------------------------------------------- executor
 class HooiExecutor:
     """Runs distributed HOOI sweeps on one ``ranks`` mesh, caching both the
@@ -237,7 +261,9 @@ class HooiExecutor:
         self.mesh = mesh if mesh is not None else make_ranks_mesh(self.P)
         self._lock = threading.RLock()
         self._steps: dict[tuple, object] = {}  # static sig -> jitted callable
-        self._seen_shapes: set[tuple] = set()  # (static sig, arg shapes)
+        # (static sig, arg shapes) -> abstract arguments (op_scopes lowers
+        # the step again from them)
+        self._seen_shapes: dict[tuple, tuple] = {}
         self._uploads: "weakref.WeakKeyDictionary[PartitionPlan, _PlanUpload]" \
             = weakref.WeakKeyDictionary()
         # an auto plan is a dataclasses.replace copy of its winning
@@ -441,29 +467,30 @@ class HooiExecutor:
                     # a re-created callable gets a fresh jit cache: its
                     # compilations must be counted again
                     self._seen_shapes = {
-                        s for s in self._seen_shapes if s[0] != old}
+                        s: v for s, v in self._seen_shapes.items()
+                        if s[0] != old}
         return skey, step
 
-    def _note_shapes(self, skey, shapes, tally: dict) -> None:
+    def _note_shapes(self, skey, args: tuple, tally: dict) -> None:
         # jit compiles exactly when it first sees a shape signature for this
         # callable; mirror that condition to count compilations faithfully.
-        # ``tally`` is the per-run ledger: concurrent runs on one shared
-        # executor must not read each other's work out of the cumulative
-        # counters.
+        # ``args`` are the step's arguments; ``tally`` is the per-run
+        # ledger: concurrent runs on one shared executor must not read each
+        # other's work out of the cumulative counters.
+        sig = (skey, tuple(a.shape for a in jax.tree.leaves(args)))
         with self._lock:
-            if (skey, shapes) in self._seen_shapes:
+            if sig in self._seen_shapes:
                 self._stats["step_cache_hits"] += 1
                 tally["step_cache_hits"] += 1
             else:
-                self._seen_shapes.add((skey, shapes))
+                self._seen_shapes[sig] = jax.tree.map(_abstract, args)
                 self._stats["step_compilations"] += 1
                 tally["step_compilations"] += 1
 
     def _call_step(self, skey, step, dev_args, factors, key, tally: dict):
-        shapes = tuple(a.shape for a in dev_args) + tuple(
-            f.shape for f in factors)
-        self._note_shapes(skey, shapes, tally)
-        return step(*dev_args, factors, key)
+        args = (*dev_args, factors, key)
+        self._note_shapes(skey, args, tally)
+        return step(*args)
 
     def _get_upload(self, pl: PartitionPlan, t: SparseTensor,
                     tally: dict) -> _PlanUpload:
@@ -514,7 +541,8 @@ class HooiExecutor:
         """
         tally = {"step_compilations": 0, "step_cache_hits": 0,
                  "uploads": 0, "upload_cache_hits": 0}
-        self._get_upload(pl, t, tally)
+        with span("hooi.upload"):
+            self._get_upload(pl, t, tally)
         return {"uploads": tally["uploads"],
                 "already_resident": tally["upload_cache_hits"] > 0}
 
@@ -575,6 +603,27 @@ class HooiExecutor:
         with self._lock:
             return dict(self._stats, cached_steps=len(self._steps),
                         cached_plans=len(self._uploads))
+
+    def op_scopes(self) -> dict[str, str]:
+        """``{"<module>/<instruction>": scope}`` for every step executable
+        this executor has run, ``scope`` one of ``repro.tracing.SCOPES``.
+
+        A device op in a profiler trace is named by its executable's module
+        (``jit_hooi_step_m0_local``) and its HLO instruction; this maps it
+        to the ``zbuild``/``oracle``/``comm`` scope it was traced under
+        (``repro.tracing.hlo_scopes``). Each cached step is lowered again
+        from the arguments noted when it first ran and compiled, which jit
+        serves from its cache. Call it after the work it describes, never
+        inside a measured window.
+        """
+        with self._lock:
+            todo = [(self._steps[sig[0]], args)
+                    for sig, args in self._seen_shapes.items()
+                    if sig[0] in self._steps]
+        out: dict[str, str] = {}
+        for step, args in todo:
+            out.update(hlo_scopes(step.lower(*args).compile().as_text()))
+        return out
 
     def calibration_samples(self) -> list[dict]:
         """Measured sweeps (flops/bytes/seconds) for ``fit_cost_model``."""
@@ -648,7 +697,6 @@ class HooiExecutor:
 
         per_mode = {}
         ttm_s = full_s = 0.0
-        fshapes = tuple(f.shape for f in factors)
         for n in range(N):
             sp = specs[n]
             zkey, zstep = self._get_step(parts[n], "zbuild", sp.K_n,
@@ -668,12 +716,8 @@ class HooiExecutor:
             # later run() on these shapes sees them as already-compiled (the
             # 0-new-compilations reuse contract) and its first sweep is not
             # mis-flagged cold
-            self._note_shapes(
-                zkey, tuple(a.shape for a in up.dev_args[n][:3]) + fshapes,
-                tally)
-            self._note_shapes(
-                skey, tuple(a.shape for a in up.dev_args[n]) + fshapes,
-                tally)
+            self._note_shapes(zkey, (*up.dev_args[n][:3], factors), tally)
+            self._note_shapes(skey, (*up.dev_args[n], factors, kk), tally)
             tz = _timed(zstep, *up.dev_args[n][:3], factors)
             tf = _timed(step, *up.dev_args[n], factors, kk)
             per_mode[n] = {"ttm_s": tz, "full_s": tf,
@@ -706,6 +750,7 @@ class HooiExecutor:
                 "per_mode": per_mode, "z_kernel": z_kernel}
 
     # ---------------------------------------------------------------- run
+    @_traced_call
     def run(
         self,
         t: SparseTensor,
@@ -775,20 +820,22 @@ class HooiExecutor:
                  "uploads": 0, "upload_cache_hits": 0}
         obj = resolve_objective(objective)
         t = obj.prepare_tensor(t)
-        t_plan = time.perf_counter()
-        if isinstance(scheme, PartitionPlan):
-            pl = scheme
-            self._check_plan(pl, t, core_dims, path, obj.name)
-            cache_hit = False
-        else:
-            pl = build_plan(t, scheme, self.P, core_dims=tuple(core_dims),
-                            path=path, seed=plan_seed,
-                            pad_geometric=pad_geometric, objective=obj)
-            # thread-local outcome: differencing the global miss counter
-            # misreports hits when a concurrent submitter builds a plan in
-            # the same window (the pool's producer threads routinely do)
-            cache_hit = last_plan_call_cache_hit()
-        partition_build_s = time.perf_counter() - t_plan
+        with span("hooi.plan") as sp:
+            if isinstance(scheme, PartitionPlan):
+                pl = scheme
+                self._check_plan(pl, t, core_dims, path, obj.name)
+                cache_hit = False
+            else:
+                pl = build_plan(t, scheme, self.P,
+                                core_dims=tuple(core_dims), path=path,
+                                seed=plan_seed, pad_geometric=pad_geometric,
+                                objective=obj)
+                # thread-local outcome: differencing the global miss
+                # counter misreports hits when a concurrent submitter
+                # builds a plan in the same window (the pool's producer
+                # threads routinely do)
+                cache_hit = last_plan_call_cache_hit()
+        partition_build_s = sp.seconds
 
         N = t.ndim
         key = jax.random.PRNGKey(seed)
@@ -818,7 +865,8 @@ class HooiExecutor:
                                 objective=specs[n].objective,
                                 warm_start=specs[n].warm_start)
                  for n in range(N)]
-        up = self._get_upload(pl, t, tally)
+        with span("hooi.upload"):
+            up = self._get_upload(pl, t, tally)
         backend_label = _backend_label(specs)
         run_bytes = _run_comm_bytes(pl, specs)
 
@@ -936,7 +984,8 @@ class HooiExecutor:
                     old = next(iter(self._steps))
                     del self._steps[old]
                     self._seen_shapes = {
-                        s for s in self._seen_shapes if s[0] != old}
+                        s: v for s, v in self._seen_shapes.items()
+                        if s[0] != old}
         return skey, step
 
     def _get_stoch_upload(self, t: SparseTensor, obj, sb,
@@ -996,9 +1045,11 @@ class HooiExecutor:
                     old = next(iter(self._steps))
                     del self._steps[old]
                     self._seen_shapes = {
-                        s for s in self._seen_shapes if s[0] != old}
+                        s: v for s, v in self._seen_shapes.items()
+                        if s[0] != old}
         return skey, fn
 
+    @_traced_call
     def run_stochastic(
         self,
         t: SparseTensor,
@@ -1101,9 +1152,7 @@ class HooiExecutor:
 
         def mode_step(n, facs, kk):
             skey, step = steps[n]
-            shapes = (sb_coords.shape, sb_values.shape) + tuple(
-                f.shape for f in facs)
-            self._note_shapes(skey, shapes, tally)
+            self._note_shapes(skey, (sb_coords, sb_values, facs, kk), tally)
             left, sv = step(sb_coords, sb_values, facs, kk)
             spectra[n] = sv
             blended = blend_factor(facs[n], left, eta)
@@ -1119,9 +1168,8 @@ class HooiExecutor:
                                     key, n_invocations, mode_step,
                                     objective=obj)
         ckey, core_fn = self._get_stoch_core()
-        self._note_shapes(
-            ckey, (full_coords.shape, full_values.shape) + tuple(
-                f.shape for f in dec.factors), tally)
+        self._note_shapes(ckey, (full_coords, full_values, dec.factors),
+                          tally)
         core = obj.finalize_core(
             core_fn(full_coords, full_values, dec.factors), dec.factors)
         dec = Decomposition(core=core, factors=dec.factors)
@@ -1154,6 +1202,14 @@ class HooiExecutor:
             step_size=float(eta),
         )
         return dec, stats
+
+
+def _abstract(a) -> jax.ShapeDtypeStruct:
+    """A step argument as jit saw it: shape, dtype, and its sharding when
+    the array is committed to devices (an uncommitted one has none)."""
+    return jax.ShapeDtypeStruct(
+        a.shape, a.dtype, weak_type=a.aval.weak_type,
+        sharding=a.sharding if getattr(a, "committed", True) else None)
 
 
 def _coerce_factors(factors, shape: Sequence[int],

@@ -31,7 +31,8 @@ Three backends, selected per mode from the plan's partition metrics
 
 All backends assume they run inside ``shard_map`` over the ``"ranks"`` axis
 (``local`` merely never issues a collective, so its 1-device mesh is
-degenerate by construction).
+degenerate by construction). Collectives and the boundary exchange run
+under the named scope ``comm``, nested in the stage that issues them.
 """
 
 from __future__ import annotations
@@ -149,11 +150,14 @@ def _psum_space(ms: dict, arrs: dict, zmv, zrmv) -> OracleSpace:
     def wrap(local):  # (R_pad, ...) local Z product -> replicated row space
         out = jnp.zeros((L_sent,) + local.shape[1:], local.dtype).at[
             row_gid].add(local, mode="drop")
-        return jax.lax.psum(out, AXIS)
+        with jax.named_scope("comm"):
+            return jax.lax.psum(out, AXIS)
 
     def rmatvec(u):
         y_loc = u.at[row_gid].get(mode="fill", fill_value=0.0)
-        return jax.lax.psum(zrmv(y_loc), AXIS)
+        part = zrmv(y_loc)
+        with jax.named_scope("comm"):
+            return jax.lax.psum(part, AXIS)
 
     def finalize(left):  # (L_sent, k) replicated -> (Lp, k) shard
         return jax.lax.dynamic_slice_in_dim(left, p * Lp, Lp, 0)
@@ -178,25 +182,30 @@ def _boundary_space(ms: dict, arrs: dict, zmv, zrmv) -> OracleSpace:
         shard = jnp.zeros((Lp,) + local.shape[1:], local.dtype).at[
             jnp.where(row_owned, off, Lp)
         ].add(owned_contrib, mode="drop")
-        # boundary rows -> tiny global slot vector (size S_pad ~ O(P))
-        bvec = jnp.zeros((S_pad,) + local.shape[1:], local.dtype).at[
-            bnd_slot].add(local, mode="drop")
-        # owned/pad rows have slot S_pad -> dropped
-        bvec = jax.lax.psum(bvec, AXIS)
-        add = bvec.at[own_bnd_slot].get(mode="fill", fill_value=0.0)
-        shard = shard.at[own_bnd_off].add(add, mode="drop")
+        with jax.named_scope("comm"):
+            # boundary rows -> tiny global slot vector (size S_pad ~ O(P))
+            bvec = jnp.zeros((S_pad,) + local.shape[1:], local.dtype).at[
+                bnd_slot].add(local, mode="drop")
+            # owned/pad rows have slot S_pad -> dropped
+            bvec = jax.lax.psum(bvec, AXIS)
+            add = bvec.at[own_bnd_slot].get(mode="fill", fill_value=0.0)
+            shard = shard.at[own_bnd_off].add(add, mode="drop")
         return shard  # (Lp, ...) sharded over ranks
 
     def rmatvec(u_shard):
-        # owners publish boundary-row values into the tiny slot vector
-        vals = u_shard.at[own_bnd_off].get(mode="fill", fill_value=0.0)
-        ybnd = jnp.zeros((S_pad,) + u_shard.shape[1:], u_shard.dtype).at[
-            own_bnd_slot].set(vals, mode="drop")
-        ybnd = jax.lax.psum(ybnd, AXIS)
+        with jax.named_scope("comm"):
+            # owners publish boundary-row values into the tiny slot vector
+            vals = u_shard.at[own_bnd_off].get(mode="fill", fill_value=0.0)
+            ybnd = jnp.zeros((S_pad,) + u_shard.shape[1:],
+                             u_shard.dtype).at[own_bnd_slot].set(
+                                 vals, mode="drop")
+            ybnd = jax.lax.psum(ybnd, AXIS)
+            y_for = ybnd.at[bnd_slot].get(mode="fill", fill_value=0.0)
         y_own = u_shard.at[off].get(mode="fill", fill_value=0.0)
-        y_for = ybnd.at[bnd_slot].get(mode="fill", fill_value=0.0)
         y_loc = jnp.where(_bmask(y_own), y_own, y_for)
-        return jax.lax.psum(zrmv(y_loc), AXIS)
+        part = zrmv(y_loc)
+        with jax.named_scope("comm"):
+            return jax.lax.psum(part, AXIS)
 
     return OracleSpace(lambda x: wrap(zmv(x)), rmatvec, Lp, AXIS,
                        lambda left: left, wrap)

@@ -94,7 +94,7 @@ class PoolStats:
     completed: int = 0
     failed: int = 0
     host_s: float = 0.0
-    device_s: float = 0.0
+    run_s: float = 0.0
     queue_wait_s: float = 0.0
     slo_hit: int = 0
     slo_miss: int = 0
@@ -177,7 +177,7 @@ class ExecutorPool:
         lane_execs = tuple(l.executor.stats() for l in self.lanes)
         decisions: collections.Counter = collections.Counter()
         agg = {"submitted": 0, "completed": 0, "failed": 0,
-               "host_s": 0.0, "device_s": 0.0, "queue_wait_s": 0.0,
+               "host_s": 0.0, "run_s": 0.0, "queue_wait_s": 0.0,
                "slo_hit": 0, "slo_miss": 0}
         for ls in lane_stats:
             for k in agg:

@@ -88,6 +88,7 @@ from repro import envknobs
 from repro.engine.objective import resolve_objective
 from repro.engine.oracle import resolve_warm_start
 from repro.streaming import StreamingTensor
+from repro.tracing import span
 
 __all__ = ["StreamScheduler", "ScheduledResult"]
 
@@ -111,7 +112,7 @@ class ScheduledResult:
     decision: str  # one of DECISIONS
     drift: dict | None  # refresh_decision output (appends only)
     prepare_s: float  # host stage: snapshot + decision + plan + staging
-    run_s: float  # device stage: sweeps (consumer thread)
+    run_s: float  # host wall of the run stage: sweeps (consumer thread)
     stream_version: int | None  # version decomposed (streams only)
     # serving-tier accounting (defaults keep pre-pool callers working):
     # time spent waiting in queues — submit -> sweep start, minus the
@@ -333,7 +334,7 @@ class StreamScheduler:
         self._burst_start: float | None = None
         self._totals = {
             "submitted": 0, "completed": 0, "failed": 0,
-            "host_s": 0.0, "device_s": 0.0,
+            "host_s": 0.0, "run_s": 0.0,
             # serving-tier aggregates (per-stream values on DistHooiStats)
             "queue_wait_s": 0.0, "slo_hit": 0, "slo_miss": 0,
         }
@@ -535,22 +536,23 @@ class StreamScheduler:
             if job.wait_event is not None:
                 job.wait_event.wait()
             try:
-                t0 = time.perf_counter()
-                if isinstance(job.source, StreamingTensor):
-                    self._prepare_stream(job, job.source)
-                else:
-                    # the objective's training view is what gets planned,
-                    # uploaded AND swept — prepare_tensor is idempotent on
-                    # its own output, so the executor sees the same object
-                    job.tensor = job.objective.prepare_tensor(job.source)
-                    job.decision = "plan"
-                    job.core_dims = self.core_dims
-                    job.plan, _ = self.executor.prepare(
-                        job.tensor, self.core_dims, self.scheme,
-                        path=self.path, plan_seed=self.plan_seed,
-                        pad_geometric=self.pad_geometric,
-                        objective=job.objective)
-                job.prepare_s = time.perf_counter() - t0
+                with span("sched.prepare", seq=job.seq) as sp:
+                    if isinstance(job.source, StreamingTensor):
+                        self._prepare_stream(job, job.source)
+                    else:
+                        # the objective's training view is what gets
+                        # planned, uploaded AND swept — prepare_tensor is
+                        # idempotent on its own output, so the executor
+                        # sees the same object
+                        job.tensor = job.objective.prepare_tensor(job.source)
+                        job.decision = "plan"
+                        job.core_dims = self.core_dims
+                        job.plan, _ = self.executor.prepare(
+                            job.tensor, self.core_dims, self.scheme,
+                            path=self.path, plan_seed=self.plan_seed,
+                            pad_geometric=self.pad_geometric,
+                            objective=job.objective)
+                job.prepare_s = sp.seconds
             finally:
                 if job.done_event is not None:
                     job.done_event.set()
@@ -831,35 +833,36 @@ class StreamScheduler:
                             int(f.shape[0]) == s
                             for f, s in zip(facs, job.tensor.shape)):
                         init = facs
-                t0 = time.perf_counter()
-                if job.stoch is not None:
-                    # the rung's budget is ONE pass — O(batch) device work
-                    # regardless of the scheduler's full-sweep invocation
-                    # count (the periodic correction sweep is what restores
-                    # full-accuracy fits)
-                    dec, stats = self.executor.run_stochastic(
-                        job.tensor, dims, job.plan,
-                        init_factors=init,
-                        covered_nnz=job.stoch["covered_nnz"],
-                        sample_fraction=self.sample_fraction,
-                        sample_seed=self.sample_seed,
-                        replay_nnz=self.replay_nnz,
-                        step_size=self.step_size,
-                        step_decay=self.step_decay,
-                        step_index=job.stoch["step_index"],
-                        n_invocations=1,
-                        seed=job.seed, use_kernel=self.use_kernel,
-                        objective=job.objective)
-                else:
-                    dec, stats = self.executor.run(
-                        job.tensor, dims, job.plan,
-                        n_invocations=job.n_invocations, path=self.path,
-                        seed=job.seed, use_kernel=self.use_kernel,
-                        use_fused_oracle=self.use_fused_oracle,
-                        objective=job.objective,
-                        warm_start=self.warm_start, init_factors=init)
-                t1 = time.perf_counter()
-                run_s = t1 - t0
+                with span("sched.run", seq=job.seq) as sp:
+                    if job.stoch is not None:
+                        # the rung's budget is ONE pass — O(batch) device
+                        # work regardless of the scheduler's full-sweep
+                        # invocation count (the periodic correction sweep
+                        # is what restores full-accuracy fits)
+                        dec, stats = self.executor.run_stochastic(
+                            job.tensor, dims, job.plan,
+                            init_factors=init,
+                            covered_nnz=job.stoch["covered_nnz"],
+                            sample_fraction=self.sample_fraction,
+                            sample_seed=self.sample_seed,
+                            replay_nnz=self.replay_nnz,
+                            step_size=self.step_size,
+                            step_decay=self.step_decay,
+                            step_index=job.stoch["step_index"],
+                            n_invocations=1,
+                            seed=job.seed, use_kernel=self.use_kernel,
+                            objective=job.objective)
+                    else:
+                        dec, stats = self.executor.run(
+                            job.tensor, dims, job.plan,
+                            n_invocations=job.n_invocations,
+                            path=self.path,
+                            seed=job.seed, use_kernel=self.use_kernel,
+                            use_fused_oracle=self.use_fused_oracle,
+                            objective=job.objective,
+                            warm_start=self.warm_start, init_factors=init)
+                t0, run_s = sp.start, sp.seconds
+                t1 = t0 + run_s
                 if src is not None:
                     self._after_stream_run(job, src, dims, dec, stats)
                 stats.stream_decision = job.decision
@@ -885,7 +888,7 @@ class StreamScheduler:
                 with self._cv:
                     self._note_finished(failed=False)
                     self._totals["host_s"] += job.prepare_s
-                    self._totals["device_s"] += run_s
+                    self._totals["run_s"] += run_s
                     self._totals["queue_wait_s"] += queue_wait
                     if slo_met is not None:
                         self._totals["slo_hit" if slo_met else
@@ -913,9 +916,10 @@ class StreamScheduler:
         ``wall_s`` is the accumulated *busy* wall time — each window runs
         from a submit into an idle pipeline until its last in-flight job
         finishes, so idle gaps between bursts do not dilute it. ``host_s``
-        and ``device_s`` are the summed stage times. ``overlap_s = host_s
-        + device_s - wall_s`` is the wall time the pipeline *hid* — what
-        sequential plan-then-sweep execution would have paid on top.
+        and ``run_s`` are the summed host wall times of the prepare and run
+        stages (the spans ``sched.prepare`` and ``sched.run``). ``overlap_s
+        = host_s + run_s - wall_s`` is the wall time the pipeline *hid* —
+        what sequential plan-then-sweep execution would have paid on top.
         """
         with self._lock:
             out = dict(self._totals)
@@ -925,5 +929,5 @@ class StreamScheduler:
                 wall += time.perf_counter() - self._burst_start
             out["wall_s"] = wall
             out["overlap_s"] = max(
-                0.0, out["host_s"] + out["device_s"] - wall) if wall else 0.0
+                0.0, out["host_s"] + out["run_s"] - wall) if wall else 0.0
             return out

@@ -3,7 +3,10 @@
 A HOOI mode step is exactly: **Z-build** (``engine.zbuild``) -> **oracle**
 (``engine.oracle``: per-device Z products + the one shared Lanczos body) ->
 **comm backend** (``engine.comm``: how the products cross the mesh). This
-module is the only place the stages meet:
+module is the only place the stages meet. Each stage runs under the
+``jax.named_scope`` of its name (``zbuild``, ``oracle``; ``comm`` nested
+where the collectives run), and each step function carries a stable name
+for its executable (``hooi_step_m<mode>_<backend>``):
 
 * ``make_mode_step_fn`` — the function ``HooiExecutor`` wraps in
   ``shard_map``/``jit`` (one per static step signature). Its positional
@@ -61,6 +64,13 @@ def f32_matmuls(fn):
     return wrapped
 
 
+def _named(fn, name: str):
+    """Give a step function the stable name its executable carries
+    (``jit_<name>`` in the compiled module and in a trace)."""
+    fn.__name__ = fn.__qualname__ = name
+    return fn
+
+
 def make_zbuild_step_fn(ms: dict, use_kernel: bool, precision: str = "f32"):
     """TTM-only step: just the local Z build (per-phase calibration probe)."""
 
@@ -69,12 +79,13 @@ def make_zbuild_step_fn(ms: dict, use_kernel: bool, precision: str = "f32"):
         # shard_map keeps a leading size-1 'ranks' axis on sharded operands
         coords, values, local_rows = (
             x[0] for x in (coords, values, local_rows))
-        Z = build_local_z(coords, values, local_rows, factors,
-                          ms["mode"], ms["R_pad"], use_kernel=use_kernel,
-                          precision=precision)
+        with jax.named_scope("zbuild"):
+            Z = build_local_z(coords, values, local_rows, factors,
+                              ms["mode"], ms["R_pad"], use_kernel=use_kernel,
+                              precision=precision)
         return Z[None]
 
-    return fn
+    return _named(fn, f"hooi_zbuild_m{ms['mode']}")
 
 
 def make_mode_step_fn(ms: dict, backend: str, K_n: int, niter: int):
@@ -115,52 +126,57 @@ def make_mode_step_fn(ms: dict, backend: str, K_n: int, niter: int):
                     own_bnd_slot=own_bnd_slot, own_bnd_off=own_bnd_off)
         use_kernel = ms.get("use_kernel", False)
         first_panel = first_product = None
-        if fused_zbuild:
-            Khat = 1
-            for j, f in enumerate(factors):
-                if j != ms["mode"]:
-                    Khat *= int(f.shape[1])
-            first_panel = block_start_panel(key, Khat, block_size)
-            Z, ZV1 = build_local_z_oracle(
-                coords, values, local_rows, factors, ms["mode"], ms["R_pad"],
-                first_panel, use_kernel=use_kernel, precision=precision)
-        else:
-            Z = build_local_z(coords, values, local_rows, factors,
-                              ms["mode"], ms["R_pad"], use_kernel=use_kernel,
-                              precision=precision)
-        zmv, zrmv = z_products(Z, fused=ms.get("use_fused", False))
-        space = make_comm_space(backend, ms, arrs, zmv, zrmv)
-        if warm_start == "sketch":
-            # original row id per local Z row, recovered from the element
-            # coords (padding elements carry coord 0 and land on the last
-            # real row's slot, where max() keeps the real id; element-free
-            # rows stay 0 — their Z row is zero, so the gathered factor row
-            # contributes nothing either way)
-            F_n = factors[ms["mode"]]
-            orig = jnp.zeros((ms["R_pad"],), jnp.int32).at[local_rows].max(
-                coords[:, ms["mode"]])
-            w = min(block_size, int(F_n.shape[1]))
-            seed = Z.T @ F_n.at[orig].get(mode="fill", fill_value=0.0)[:, :w]
-            if backend != "local":
-                seed = jax.lax.psum(seed, AXIS)
-            first_panel = seeded_start_panel(seed, key, Z.shape[1],
-                                             block_size)
-            first_panel = power_refine(space.matvec, space.rmatvec,
-                                       first_panel, DEFAULT_POWER_ITERS)
-        if warm_start == "sketch" or fused_zbuild or block_size > 1:
+        with jax.named_scope("zbuild"):
             if fused_zbuild:
-                first_product = space.wrap_matvec_out(ZV1)
-            left, S = solve_oracle_block(
-                space.matvec, space.rmatvec, space.dim_u, Z.shape[1], K_n,
-                niter, block_size, key, axis=space.axis,
-                first_panel=first_panel, first_product=first_product)
-        else:
-            left, S = solve_oracle(space.matvec, space.rmatvec, space.dim_u,
-                                   Z.shape[1], K_n, niter, key,
-                                   axis=space.axis)
-        return space.finalize(left), S
+                Khat = 1
+                for j, f in enumerate(factors):
+                    if j != ms["mode"]:
+                        Khat *= int(f.shape[1])
+                first_panel = block_start_panel(key, Khat, block_size)
+                Z, ZV1 = build_local_z_oracle(
+                    coords, values, local_rows, factors, ms["mode"],
+                    ms["R_pad"], first_panel, use_kernel=use_kernel,
+                    precision=precision)
+            else:
+                Z = build_local_z(coords, values, local_rows, factors,
+                                  ms["mode"], ms["R_pad"],
+                                  use_kernel=use_kernel, precision=precision)
+        with jax.named_scope("oracle"):
+            zmv, zrmv = z_products(Z, fused=ms.get("use_fused", False))
+            space = make_comm_space(backend, ms, arrs, zmv, zrmv)
+            if warm_start == "sketch":
+                # original row id per local Z row, recovered from the
+                # element coords (padding elements carry coord 0 and land on
+                # the last real row's slot, where max() keeps the real id;
+                # element-free rows stay 0 — their Z row is zero, so the
+                # gathered factor row contributes nothing either way)
+                F_n = factors[ms["mode"]]
+                orig = jnp.zeros((ms["R_pad"],), jnp.int32).at[
+                    local_rows].max(coords[:, ms["mode"]])
+                w = min(block_size, int(F_n.shape[1]))
+                seed = Z.T @ F_n.at[orig].get(
+                    mode="fill", fill_value=0.0)[:, :w]
+                if backend != "local":
+                    with jax.named_scope("comm"):
+                        seed = jax.lax.psum(seed, AXIS)
+                first_panel = seeded_start_panel(seed, key, Z.shape[1],
+                                                 block_size)
+                first_panel = power_refine(space.matvec, space.rmatvec,
+                                           first_panel, DEFAULT_POWER_ITERS)
+            if warm_start == "sketch" or fused_zbuild or block_size > 1:
+                if fused_zbuild:
+                    first_product = space.wrap_matvec_out(ZV1)
+                left, S = solve_oracle_block(
+                    space.matvec, space.rmatvec, space.dim_u, Z.shape[1],
+                    K_n, niter, block_size, key, axis=space.axis,
+                    first_panel=first_panel, first_product=first_product)
+            else:
+                left, S = solve_oracle(space.matvec, space.rmatvec,
+                                       space.dim_u, Z.shape[1], K_n, niter,
+                                       key, axis=space.axis)
+            return space.finalize(left), S
 
-    return fn
+    return _named(fn, f"hooi_step_m{ms['mode']}_{backend}")
 
 
 def make_stochastic_step_fn(mode: int, num_rows: int, K_n: int, niter: int,
@@ -190,21 +206,23 @@ def make_stochastic_step_fn(mode: int, num_rows: int, K_n: int, niter: int,
 
     @f32_matmuls
     def fn(coords, values, factors, key):
-        Z = build_local_z(coords, values, coords[:, mode], factors, mode,
-                          num_rows, use_kernel=use_kernel, sorted_rows=False,
-                          precision=precision)
-        matvec, rmatvec = z_products(Z)
-        Khat = int(Z.shape[1])
-        seed = Z.T @ factors[mode][:, :min(int(block_size), K_n)]
-        first_panel = seeded_start_panel(seed, key, Khat, block_size)
-        first_panel = power_refine(matvec, rmatvec, first_panel,
-                                   DEFAULT_POWER_ITERS)
-        U, B = gk_block_bidiag(matvec, rmatvec, num_rows, Khat, niter,
-                               block_size, key, axis=None,
-                               first_panel=first_panel)
-        return svd_from_bidiag(U, B, K_n, key, axis=None)
+        with jax.named_scope("zbuild"):
+            Z = build_local_z(coords, values, coords[:, mode], factors, mode,
+                              num_rows, use_kernel=use_kernel,
+                              sorted_rows=False, precision=precision)
+        with jax.named_scope("oracle"):
+            matvec, rmatvec = z_products(Z)
+            Khat = int(Z.shape[1])
+            seed = Z.T @ factors[mode][:, :min(int(block_size), K_n)]
+            first_panel = seeded_start_panel(seed, key, Khat, block_size)
+            first_panel = power_refine(matvec, rmatvec, first_panel,
+                                       DEFAULT_POWER_ITERS)
+            U, B = gk_block_bidiag(matvec, rmatvec, num_rows, Khat, niter,
+                                   block_size, key, axis=None,
+                                   first_panel=first_panel)
+            return svd_from_bidiag(U, B, K_n, key, axis=None)
 
-    return fn
+    return _named(fn, f"hooi_stoch_step_m{mode}")
 
 
 @f32_matmuls
@@ -224,14 +242,13 @@ def local_mode_step(
     block_size: int = 1,
     fused_zbuild: bool = False,
     warm_start: str = "none",
-    timings: dict | None = None,
     objective=None,
 ) -> jnp.ndarray:
     """One single-process mode step (identity partition, local backend).
 
-    Returns the refined factor (num_rows, k). ``timings`` (optional)
-    accumulates blocking per-phase wall times under ``"ttm"``/``"svd"`` —
-    the instrumentation ``hooi_invocation`` has always offered.
+    Returns the refined factor (num_rows, k). The Z build runs under the
+    named scope ``zbuild`` and the solve under ``oracle``, as in the
+    distributed steps.
 
     ``block_size``/``fused_zbuild`` route through the same block driver and
     fused build stage the distributed steps use, with the identity
@@ -252,8 +269,6 @@ def local_mode_step(
     sweeps for free. Sketch excludes ``fused_zbuild`` (the panel depends on
     Z, which the fused first product must precede).
     """
-    import time
-
     k = int(factors[mode].shape[1]) if k is None else int(k)
     Khat = 1
     for j, f in enumerate(factors):
@@ -266,47 +281,40 @@ def local_mode_step(
         # for callers that already widened via sketch_block_size)
         block_size = sketch_block_size(k, num_rows, Khat, block_size)
     blockish = fused_zbuild or block_size > 1 or warm_start == "sketch"
-    t0 = time.perf_counter()
     first_panel = first_product = None
-    if fused_zbuild:
-        first_panel = block_start_panel(key, Khat, block_size)
-        Z, first_product = build_local_z_oracle(
-            coords, values, coords[:, mode], factors, mode, num_rows,
-            first_panel, use_kernel=use_kernel, sorted_rows=False,
-            precision=precision)
-    else:
-        Z = build_local_z(coords, values, coords[:, mode], factors, mode,
-                          num_rows, use_kernel=use_kernel, sorted_rows=False,
-                          precision=precision)
-    if timings is not None:
-        Z.block_until_ready()
-    t1 = time.perf_counter()
-    matvec, rmatvec = z_products(Z, fused=use_fused_oracle)
+    with jax.named_scope("zbuild"):
+        if fused_zbuild:
+            first_panel = block_start_panel(key, Khat, block_size)
+            Z, first_product = build_local_z_oracle(
+                coords, values, coords[:, mode], factors, mode, num_rows,
+                first_panel, use_kernel=use_kernel, sorted_rows=False,
+                precision=precision)
+        else:
+            Z = build_local_z(coords, values, coords[:, mode], factors, mode,
+                              num_rows, use_kernel=use_kernel,
+                              sorted_rows=False, precision=precision)
     if niter is None:
         niter = (sketch_niter(k, num_rows, Khat, block_size)
                  if warm_start == "sketch"
                  else lanczos_niter(k, num_rows, Khat,
                                     block_size if blockish else 1))
-    if warm_start == "sketch":
-        seed = Z.T @ factors[mode][:, :min(block_size, k)]
-        first_panel = seeded_start_panel(seed, key, Khat, block_size)
-        first_panel = power_refine(matvec, rmatvec, first_panel,
-                                   DEFAULT_POWER_ITERS)
-    if blockish:
-        U, B = gk_block_bidiag(matvec, rmatvec, num_rows, Khat, niter,
-                               block_size, key, axis=None,
-                               first_panel=first_panel,
-                               first_product=first_product)
-        left, S = svd_from_bidiag(U, B, k, key, axis=None)
-    else:
-        res = lanczos_bidiag(matvec, rmatvec, num_rows, Khat, k,
-                             niter=niter, key=key)
-        left, S = res.left_vectors, res.singular_values
+    with jax.named_scope("oracle"):
+        matvec, rmatvec = z_products(Z, fused=use_fused_oracle)
+        if warm_start == "sketch":
+            seed = Z.T @ factors[mode][:, :min(block_size, k)]
+            first_panel = seeded_start_panel(seed, key, Khat, block_size)
+            first_panel = power_refine(matvec, rmatvec, first_panel,
+                                       DEFAULT_POWER_ITERS)
+        if blockish:
+            U, B = gk_block_bidiag(matvec, rmatvec, num_rows, Khat, niter,
+                                   block_size, key, axis=None,
+                                   first_panel=first_panel,
+                                   first_product=first_product)
+            left, S = svd_from_bidiag(U, B, k, key, axis=None)
+        else:
+            res = lanczos_bidiag(matvec, rmatvec, num_rows, Khat, k,
+                                 niter=niter, key=key)
+            left, S = res.left_vectors, res.singular_values
     if objective is not None:
         left = objective.refine_factor(left, S)
-    if timings is not None:
-        left.block_until_ready()
-        t2 = time.perf_counter()
-        timings["ttm"] = timings.get("ttm", 0.0) + (t1 - t0)
-        timings["svd"] = timings.get("svd", 0.0) + (t2 - t1)
     return left

@@ -12,15 +12,22 @@ mode ``n`` receives ``sweep_key(key, it, N, n)``. Every backend therefore
 draws identical Lanczos start/restart vectors for the same (seed, it, n),
 which is what makes ``hooi(t, ...)`` and ``dist_hooi(t, ..., P=1)`` produce
 the same fit trajectory.
+
+Each sweep runs under the span ``hooi.sweep`` (``repro.tracing``), with the
+children ``hooi.step`` (one per mode: the step's dispatch and whatever the
+``mode_step`` callable does on the host), ``hooi.wait`` (blocking until
+the factors are ready), ``hooi.core`` (the core and its finalization) and
+``hooi.fit``.
 """
 
 from __future__ import annotations
 
-import time
 from typing import Callable, Sequence
 
 import jax
 import jax.numpy as jnp
+
+from repro.tracing import span
 
 __all__ = ["sweep_key", "run_hooi_sweeps"]
 
@@ -47,7 +54,8 @@ def run_hooi_sweeps(
     ``mode_step(n, factors, key) -> new factor`` must return the refined
     mode-n factor in *original* row order (distributed steps undo their row
     relabeling before returning). ``on_sweep(it, seconds, fit)`` observes
-    each sweep's blocking wall time — the executor's calibration hook. The
+    each sweep's blocking wall time (its ``hooi.step`` and ``hooi.wait``
+    spans) — the executor's calibration hook. The
     core is (re)finalized from the final factors, so ``n_invocations=0``
     still yields a valid decomposition of the bootstrap factors.
 
@@ -64,24 +72,35 @@ def run_hooi_sweeps(
     fits: list[float] = []
     core = None
     for it in range(n_invocations):
-        t0 = time.perf_counter()
-        for n in range(N):
-            factors[n] = mode_step(n, factors, sweep_key(key, it, N, n))
-        jax.block_until_ready(factors)
-        sweep_s = time.perf_counter() - t0
-        core = core_from_factors(coords, values, factors)
-        if objective is None:
-            fit = fit_score(t, Decomposition(core=core, factors=factors))
-        else:
-            core = objective.finalize_core(core, factors)
-            fit = objective.fit(t, core, factors)
-            if metrics_out is not None:
-                objective.sweep_metrics(metrics_out, t, core, factors)
-        fits.append(fit)
-        if on_sweep is not None:
-            on_sweep(it, sweep_s, fit)
+        with span("hooi.sweep", it=it):
+            sweep_s = 0.0
+            for n in range(N):
+                with span("hooi.step", it=it, mode=n) as sp:
+                    factors[n] = mode_step(n, factors,
+                                           sweep_key(key, it, N, n))
+                sweep_s += sp.seconds
+            with span("hooi.wait", it=it) as sp:
+                jax.block_until_ready(factors)
+            sweep_s += sp.seconds
+            with span("hooi.core", it=it):
+                core = core_from_factors(coords, values, factors)
+                if objective is not None:
+                    core = objective.finalize_core(core, factors)
+            with span("hooi.fit", it=it):
+                if objective is None:
+                    fit = fit_score(t, Decomposition(core=core,
+                                                     factors=factors))
+                else:
+                    fit = objective.fit(t, core, factors)
+                    if metrics_out is not None:
+                        objective.sweep_metrics(metrics_out, t, core,
+                                                factors)
+            fits.append(fit)
+            if on_sweep is not None:
+                on_sweep(it, sweep_s, fit)
     if core is None:  # n_invocations == 0: finalize the initial factors
-        core = core_from_factors(coords, values, factors)
-        if objective is not None:
-            core = objective.finalize_core(core, factors)
+        with span("hooi.core"):
+            core = core_from_factors(coords, values, factors)
+            if objective is not None:
+                core = objective.finalize_core(core, factors)
     return Decomposition(core=core, factors=factors), fits

@@ -134,7 +134,7 @@ def test_compiled_step_cache_is_bounded(monkeypatch):
             self.mode, self.R_pad, self.Lp, self.S_pad = mode, 8, 3, 1
 
     k0, s0 = ex._get_step(FakeMP(0), "liteopt", 2)
-    ex._seen_shapes.add((k0, ("fake",)))
+    ex._seen_shapes[(k0, ("fake",))] = ()  # shape signature -> its args
     k1, _ = ex._get_step(FakeMP(1), "liteopt", 2)
     assert ex._get_step(FakeMP(0), "liteopt", 2)[1] is s0  # hit -> MRU
     k2, _ = ex._get_step(FakeMP(2), "liteopt", 2)  # evicts k1 (LRU), not k0

@@ -58,7 +58,7 @@ def test_pipeline_preserves_order_and_trajectories(scheduler, executor,
     assert direct.fits == res[0].fits
     st = scheduler.stats()
     assert st["completed"] == 2 and st["failed"] == 0
-    assert st["host_s"] > 0 and st["device_s"] > 0 and st["wall_s"] > 0
+    assert st["host_s"] > 0 and st["run_s"] > 0 and st["wall_s"] > 0
     assert st["decisions"] == {"plan": 2}
 
 
