@@ -66,9 +66,9 @@ def dist_hooi(
     ``"liteopt"`` -> boundary, ``"auto"`` -> per mode from the plan's
     analytic comm model; P=1 always runs the collective-free ``local``
     backend — the same engine instantiation as single-process ``hooi``).
-    ``use_kernel`` picks the Z-build variant (None = Pallas kron_segsum on
+    ``use_kernel`` picks the Z-build variant (None = a Pallas kernel on
     TPU when it fits VMEM, True = force kernel, False = jnp reference; see
-    ``repro.engine.zbuild.resolve_kernel``) and ``use_fused_oracle``
+    ``repro.engine.zbuild.resolve_zbuild``) and ``use_fused_oracle``
     (None/False = off) routes the Lanczos oracle products through the fused
     Pallas kernel. ``precision``/``lanczos_block``/``fused_zbuild`` are the
     roofline knobs (bf16 Z-build contributions, s-step Lanczos panels, the

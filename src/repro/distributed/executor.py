@@ -82,6 +82,8 @@ from repro.engine import (
 )
 from repro.engine import zbuild as engine_zbuild
 from repro.engine.objective import resolve_objective
+from repro.kernels import ops as kernel_ops
+from repro.kernels.window_segsum import pair_count, window_geometry
 from repro.tracing import Tally, hlo_scopes, span
 from .partition import comm_model, make_mode_partition  # noqa: F401 — re-export
 
@@ -132,8 +134,15 @@ class DistHooiStats:
     uploads: int = 0  # host->device arrays transferred this call
     upload_cache_hit: bool = False  # plan's device arrays were already resident
     executor: dict | None = None  # cumulative HooiExecutor.stats() snapshot
-    # mode -> True if the Z build ran through the Pallas kron_segsum kernel
+    # mode -> True if the Z build ran through a Pallas kernel
     z_kernel: dict | None = None
+    # mode -> which Z build ran: "windowed" | "resident" | "reference"
+    # (repro.engine.zbuild.ZBUILDS)
+    z_build: dict | None = None
+    # windowed modes -> (element block, row window) pairs the kernel worked
+    # on per element block, over all ranks: 1.0 when no block crosses a
+    # window boundary (kernels.window_segsum.pair_count)
+    z_pairs_per_block: dict | None = None
     # mode -> comm backend the step ran ("local" | "psum" | "boundary")
     comm_backends: dict | None = None
     # True when the Lanczos oracle products ran the fused Pallas kernel
@@ -211,6 +220,8 @@ class _PlanUpload:
     coords: jnp.ndarray  # full-tensor COO (core / fit evaluation)
     values: jnp.ndarray
     n_arrays: int
+    # (mode, precision) -> windowed pairs per element block, counted once
+    pairs_per_block: dict = dataclasses.field(default_factory=dict)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -224,12 +235,25 @@ class _ModeSpec:
     backend: str
     K_n: int
     niter: int  # block iterations when block_size > 1
-    use_kernel: bool
+    zbuild: str  # Z-build variant (repro.engine.zbuild.ZBUILDS)
     precision: str = "f32"
     block_size: int = 1  # effective (clamped) Lanczos panel width
     fused_zbuild: bool = False
     objective: str = "tucker"  # sweep objective the step runs under
     warm_start: str = "none"  # resolved oracle warm start ("none"|"sketch")
+
+    @property
+    def use_kernel(self) -> bool:
+        return self.zbuild != "reference"
+
+
+def _pairs_per_block(mp, core_dims: Sequence[int], precision: str) -> float:
+    """Windowed-kernel pairs per element block over every rank's elements
+    of one mode partition (host count from the sorted local rows)."""
+    Ka, Kb = kernel_ops.split_kron_dims(core_dims, mp.mode)
+    block_e = window_geometry(Ka, Kb, precision).block_e
+    pairs = sum(pair_count(mp.local_rows[p], block_e) for p in range(mp.P))
+    return pairs / (mp.P * -(-mp.E_pad // block_e))
 
 
 def _traced_call(run):
@@ -293,12 +317,12 @@ class HooiExecutor:
         }
 
     # ------------------------------------------------------------ kernels
-    def resolve_kernel(self, mp, core_dims: Sequence[int],
-                       use_kernel: bool | None, oracle_s: int = 0) -> bool:
-        """Static kernel/fallback decision for one mode step's Z build
-        (delegates to the engine's shared gate — see
-        ``repro.engine.zbuild.resolve_kernel``)."""
-        return engine_zbuild.resolve_kernel(mp.R_pad, core_dims, mp.mode,
+    def resolve_zbuild(self, mp, core_dims: Sequence[int],
+                       use_kernel: bool | None, oracle_s: int = 0) -> str:
+        """Static Z-build variant of one mode step (delegates to the
+        engine's shared gate — see ``repro.engine.zbuild.resolve_zbuild``;
+        partitions hold their elements sorted by local row)."""
+        return engine_zbuild.resolve_zbuild(mp.R_pad, core_dims, mp.mode,
                                             use_kernel, oracle_s)
 
     # ------------------------------------------------------------ planning
@@ -346,7 +370,7 @@ class HooiExecutor:
           *true* row count and the effective K_hat — the same numbers the
           local engine path derives, so P=1 trajectories coincide. Counts
           *block* iterations when the mode runs the block driver.
-        * ``use_kernel``: the VMEM-gated Z-build choice, evaluated on the
+        * ``zbuild``: the VMEM-gated Z-build variant, evaluated on the
           actual factor widths ``min(L_n, K_n)`` (``random_factors``'
           reduced QR clamps K > L), not the raw request.
         * ``precision``/``block_size``/``fused_zbuild``: the *resolved*
@@ -391,7 +415,7 @@ class HooiExecutor:
                 backend=backend,
                 K_n=K_n,
                 niter=niter,
-                use_kernel=self.resolve_kernel(
+                zbuild=self.resolve_zbuild(
                     mp, eff, use_kernel, s_eff if fz_n else 0),
                 precision=precision,
                 block_size=s_eff,
@@ -784,7 +808,7 @@ class HooiExecutor:
         ``"liteopt"`` (boundary) or ``"auto"`` (per mode from the plan's
         analytic comm model); P=1 always resolves to the collective-free
         ``local`` backend. ``use_kernel`` selects the Z-build variant per
-        mode step (see ``repro.engine.zbuild.resolve_kernel``);
+        mode step (see ``repro.engine.zbuild.resolve_zbuild``);
         ``use_fused_oracle`` (None/False = off) routes the Lanczos oracle
         products through the fused Pallas kernel.
 
@@ -867,6 +891,15 @@ class HooiExecutor:
                  for n in range(N)]
         with span("hooi.upload"):
             up = self._get_upload(pl, t, tally)
+        eff = [min(int(k), int(mp.L)) for k, mp in zip(core_dims, parts)]
+        z_pairs = {}
+        for n in range(N):
+            if specs[n].zbuild == "windowed":
+                pkey = (n, prec)
+                if pkey not in up.pairs_per_block:
+                    up.pairs_per_block[pkey] = _pairs_per_block(
+                        parts[n], eff, prec)
+                z_pairs[n] = up.pairs_per_block[pkey]
         backend_label = _backend_label(specs)
         run_bytes = _run_comm_bytes(pl, specs)
 
@@ -935,6 +968,8 @@ class HooiExecutor:
             upload_cache_hit=tally["upload_cache_hits"] > 0,
             executor=self.stats(),
             z_kernel=z_kernel,
+            z_build={n: specs[n].zbuild for n in range(N)},
+            z_pairs_per_block=z_pairs or None,
             comm_backends={n: specs[n].backend for n in range(N)},
             fused_oracle=fused,
             precision=prec,
@@ -1188,6 +1223,8 @@ class HooiExecutor:
             upload_cache_hit=tally["upload_cache_hits"] > 0,
             executor=self.stats(),
             z_kernel=z_kernel,
+            z_build={n: "resident" if k else "reference"
+                     for n, k in z_kernel.items()},
             comm_backends={n: "local" for n in range(N)},
             precision=prec,
             lanczos_block=lanczos_block,

@@ -66,6 +66,7 @@ from .zbuild import (
     kernel_forced_by_env,
     resolve_fused_zbuild,
     resolve_kernel,
+    resolve_zbuild,
     resolve_precision,
 )
 
@@ -106,6 +107,7 @@ __all__ = [
     "build_local_z_oracle",
     "kernel_forced_by_env",
     "resolve_kernel",
+    "resolve_zbuild",
     "resolve_precision",
     "resolve_fused_zbuild",
 ]

@@ -1,17 +1,26 @@
 """Z-build stage: the §4.3 TTM hot spot, one implementation for every path.
 
 Each HOOI mode step first materializes the (local) penultimate matrix
-``Z = segment_sum(kron_contributions, rows)``. Two variants exist — the
-pure-jnp reference and the Pallas ``kron_segsum`` kernel (the one-hot-matmul
-reformulation, ``repro.kernels``) — and the choice is *static*: it is baked
-into the trace, so executors must key compiled steps on it.
+``Z = segment_sum(kron_contributions, rows)``. Three variants exist, and the
+choice is *static*: it is baked into the trace, so executors must key
+compiled steps on it.
 
-``resolve_kernel`` is the one gate: VMEM admission (``tile_geometry``) plus
-the backend policy. ``use_kernel=None`` auto-engages the kernel on a real
-TPU backend only (off-TPU it would run in interpret mode, far slower than
-the reference) — unless the ``REPRO_FORCE_KERNEL=1`` environment variable is
-set, which treats ``None`` as "kernel wherever it fits" so CI can run the
-whole fast suite through the interpret-mode kernel path as a blocking job.
+* ``"windowed"`` — the Pallas ``window_segsum`` kernel (``repro.kernels``)
+  for elements sorted by local row (the partition contract every
+  distributed step runs under): one-hot matmuls into 128-row windows of a
+  Z that stays in HBM, chunk by chunk. Its VMEM depends on K̂ only.
+* ``"resident"`` — the Pallas ``kron_segsum`` kernel, which holds the whole
+  Z tile in VMEM: the unsorted single-process path (it sorts first) and
+  the fused Z-build→oracle stage, up to the rows its VMEM gate admits.
+* ``"reference"`` — the chunked jnp ``segment_sum``.
+
+``resolve_zbuild`` (sorted rows) and ``resolve_kernel`` (the resident
+kernel) are the gates: VMEM admission plus the backend policy.
+``use_kernel=None`` auto-engages a kernel on a real TPU backend only
+(off-TPU it would run in interpret mode, far slower than the reference) —
+unless the ``REPRO_FORCE_KERNEL=1`` environment variable is set, which
+treats ``None`` as "kernel wherever it fits" so CI can run the whole fast
+suite through the interpret-mode kernel path as a blocking job.
 """
 
 from __future__ import annotations
@@ -26,10 +35,11 @@ from repro.core.ttm import fold_elements, kron_contributions
 from repro.kernels import ops as kernel_ops
 
 __all__ = ["build_local_z", "build_local_z_oracle", "resolve_kernel",
-           "kernel_forced_by_env", "resolve_precision",
-           "resolve_fused_zbuild", "PRECISIONS"]
+           "resolve_zbuild", "kernel_forced_by_env", "resolve_precision",
+           "resolve_fused_zbuild", "PRECISIONS", "ZBUILDS"]
 
 PRECISIONS = envknobs.PRECISIONS  # historical re-export
+ZBUILDS = ("windowed", "resident", "reference")
 
 
 def kernel_forced_by_env() -> bool:
@@ -79,9 +89,23 @@ def resolve_fused_zbuild(fused_zbuild: bool | None) -> bool:
     return bool(fused_zbuild)
 
 
+def _auto_kernel(use_kernel: bool | None, fits: bool) -> bool:
+    """``use_kernel`` applied to a gate's verdict: ``True`` forces the
+    kernel wherever it fits, ``False`` pins the reference, ``None`` takes
+    the kernel where it fits on a TPU (or under ``REPRO_FORCE_KERNEL=1``)."""
+    if use_kernel is False:
+        return False
+    if use_kernel is None:
+        return fits and (jax.default_backend() == "tpu"
+                         or kernel_forced_by_env())
+    return fits
+
+
 def resolve_kernel(num_rows: int, core_dims: Sequence[int], mode: int,
                    use_kernel: bool | None, oracle_s: int = 0) -> bool:
-    """Static kernel/reference decision for one mode step's Z build.
+    """Static resident-kernel/reference decision for one mode step's Z
+    build on the paths that keep the resident ``kron_segsum`` (unsorted
+    rows, fused build).
 
     ``True`` forces the kernel wherever the VMEM gate admits the shape
     (differential tests); ``False`` pins the jnp ``segment_sum`` reference;
@@ -90,14 +114,28 @@ def resolve_kernel(num_rows: int, core_dims: Sequence[int], mode: int,
     which the fused kernel's footprint includes. The resolved choice must
     be part of any compiled-step cache key.
     """
-    if use_kernel is False:
-        return False
     Ka, Kb = kernel_ops.split_kron_dims(core_dims, mode)
-    fits = kernel_ops.kernel_fits_vmem(num_rows, Ka, Kb, oracle_s=oracle_s)
-    if use_kernel is None:
-        return fits and (jax.default_backend() == "tpu"
-                         or kernel_forced_by_env())
-    return fits
+    return _auto_kernel(use_kernel, kernel_ops.kernel_fits_vmem(
+        num_rows, Ka, Kb, oracle_s=oracle_s))
+
+
+def resolve_zbuild(num_rows: int, core_dims: Sequence[int], mode: int,
+                   use_kernel: bool | None, oracle_s: int = 0) -> str:
+    """Static Z-build variant of a sorted-rows (distributed) mode step:
+    one of ``ZBUILDS``.
+
+    The plain build (``oracle_s == 0``) takes the windowed kernel, whose
+    gate depends on K̂ alone; the fused build (``oracle_s`` > 0, the first
+    panel's width) keeps the resident kernel and its row-dependent gate.
+    ``use_kernel`` as in ``resolve_kernel``.
+    """
+    if oracle_s:
+        return ("resident" if resolve_kernel(num_rows, core_dims, mode,
+                                             use_kernel, oracle_s)
+                else "reference")
+    Ka, Kb = kernel_ops.split_kron_dims(core_dims, mode)
+    fits = kernel_ops.windowed_fits_vmem(Ka, Kb)
+    return "windowed" if _auto_kernel(use_kernel, fits) else "reference"
 
 
 def build_local_z(
@@ -114,19 +152,29 @@ def build_local_z(
 ) -> jnp.ndarray:
     """The (local) penultimate matrix Z — (num_rows, K_hat).
 
-    ``use_kernel`` routes through the Pallas ``kron_segsum`` kernel.
-    ``sorted_rows=True`` asserts the partition.py contract (per-rank
-    elements pre-sorted by dense local row id), skipping the runtime
-    argsort; the single-process path passes ``sorted_rows=False`` since raw
-    COO order is arbitrary. ``precision="bf16"`` rounds kron contributions
-    to bf16 with f32 accumulation (kernel and reference implement the same
-    contract). All flags are static (baked into the trace).
+    ``use_kernel`` routes through a Pallas kernel: with ``sorted_rows=True``
+    (the partition.py contract: per-rank elements pre-sorted by dense local
+    row id) the windowed ``window_segsum``, chunk by chunk like the
+    reference; with ``sorted_rows=False`` (the single-process path, raw COO
+    order) the resident ``kron_segsum`` after a runtime argsort.
+    ``precision="bf16"`` rounds kron contributions to bf16 with f32
+    accumulation (kernels and reference implement the same contract). All
+    flags are static (baked into the trace).
     """
-    if use_kernel:
-        fn = (kernel_ops.penultimate_sorted if sorted_rows
-              else kernel_ops.penultimate_local)
-        return fn(coords, values, local_rows, factors, mode, num_rows,
-                  use_kernel=True, precision=precision)
+    if use_kernel and not sorted_rows:
+        return kernel_ops.penultimate_local(
+            coords, values, local_rows, factors, mode, num_rows,
+            use_kernel=True, precision=precision)
+    widths = [int(f.shape[1]) for f in factors]
+    Ka, Kb = kernel_ops.split_kron_dims(widths, mode)
+
+    acc = jnp.zeros((num_rows, Ka * Kb), jnp.result_type(values, *factors))
+    if use_kernel and kernel_ops.windowed_fits_vmem(Ka, Kb,
+                                                    precision=precision):
+        step = kernel_ops.window_fold_step(local_rows, factors, mode,
+                                           num_rows, precision=precision)
+        return fold_elements(step, (acc, jnp.int32(0)), coords, values,
+                             local_rows)[0]
 
     def chunk_z(acc, coords, values, rows):
         contribs = kron_contributions(coords, values, factors, mode)
@@ -135,11 +183,6 @@ def build_local_z(
         return acc + jax.ops.segment_sum(contribs, rows,
                                          num_segments=num_rows)
 
-    khat = 1
-    for j, f in enumerate(factors):
-        if j != mode:
-            khat *= int(f.shape[1])
-    acc = jnp.zeros((num_rows, khat), jnp.result_type(values, *factors))
     return fold_elements(chunk_z, acc, coords, values, local_rows)
 
 

@@ -4,6 +4,8 @@ Public entry points used by the rest of the framework:
 
   * ``penultimate(coords, values, factors, mode, num_rows)`` — kernel-backed
     drop-in for repro.core.ttm.penultimate / penultimate_local.
+  * ``window_fold_step(rows, factors, mode, num_rows)`` — the chunk step
+    of the sorted-rows Z build through the windowed kernel.
   * ``oracle_pair(Z, x, y)`` — fused Lanczos oracle.
 
 The wrappers (i) prepare kernel-friendly layouts (fold the N-2 leading
@@ -21,14 +23,19 @@ from typing import Sequence
 import jax
 import jax.numpy as jnp
 
+from repro.core.ttm import ELEMENT_CHUNK
+
 from . import ref
 from .kron_segsum import (  # noqa: F401
     ROW_BLOCK, kron_segsum, kron_segsum_oracle, tile_geometry)
 from .oracle_fused import oracle_pair as _oracle_pair_kernel
+from .window_segsum import (chunk_pairs, mode_pairs, window_geometry,
+                            window_segsum)
 
 __all__ = ["penultimate", "penultimate_local", "penultimate_sorted",
            "penultimate_sorted_oracle", "oracle_pair", "kernel_fits_vmem",
-           "split_kron_dims", "vmem_budget_bytes"]
+           "windowed_fits_vmem", "window_fold_step", "split_kron_dims",
+           "vmem_budget_bytes"]
 
 # default admission budget for one launch's VMEM footprint
 # (``TileGeometry.vmem_bytes``), in bytes: a quarter of a v5e core's
@@ -69,6 +76,15 @@ def kernel_fits_vmem(num_rows: int, Ka: int, Kb: int,
                          oracle_s=oracle_s)
     budget = vmem_budget_bytes() if vmem_budget is None else vmem_budget
     return geom.vmem_bytes <= budget
+
+
+def windowed_fits_vmem(Ka: int, Kb: int, *, precision: str = "f32",
+                       vmem_budget: int | None = None) -> bool:
+    """Admission gate of the windowed kernel: its footprint
+    (``WindowGeometry.vmem_bytes``) depends on Ka·Kb alone, not on the
+    number of rows."""
+    budget = vmem_budget_bytes() if vmem_budget is None else vmem_budget
+    return window_geometry(Ka, Kb, precision).vmem_bytes <= budget
 
 
 def split_kron_dims(core_dims: Sequence[int], mode: int) -> tuple[int, int]:
@@ -148,6 +164,38 @@ def penultimate_sorted(
         interpret=interpret,
         precision=precision,
     )
+
+
+def window_fold_step(local_rows: jnp.ndarray, factors: Sequence[jnp.ndarray],
+                     mode: int, num_rows: int, *, precision: str = "f32"):
+    """The step of the sorted-rows Z build's ``fold_elements`` loop over
+    the carry ``(z, start)``: ``step((z, start), coords, values, rows)``
+    adds the chunk of elements from ``start`` through the windowed kernel
+    and moves ``start`` past it. The (element block, row window) pairs of
+    the whole mode are computed here, once, and each step slices its
+    chunk's, so chunks must start on block boundaries: ``block_e`` divides
+    ``core.ttm.ELEMENT_CHUNK``. The factor-row gathers and the leading
+    Kronecker levels (``_split_ab``) stay in XLA; ``z`` is the kernel's
+    aliased output."""
+    Ka, Kb = split_kron_dims([int(f.shape[1]) for f in factors], mode)
+    block_e = window_geometry(Ka, Kb, precision).block_e
+    assert ELEMENT_CHUNK % block_e == 0, (ELEMENT_CHUNK, block_e)
+    local_rows = local_rows.astype(jnp.int32)
+    pairs = (mode_pairs(local_rows, block_e, num_rows)
+             if local_rows.shape[0] else None)
+
+    def step(carry, coords, values, rows):
+        z, start = carry
+        if not rows.shape[0]:
+            return carry
+        a, b = _split_ab(coords, values, factors, mode)
+        z = window_segsum(
+            rows.astype(jnp.int32), a, b, z,
+            chunk_pairs(pairs, start, rows.shape[0], block_e, num_rows),
+            interpret=_interpret_default(), precision=precision)
+        return z, start + rows.shape[0]
+
+    return step
 
 
 def penultimate_sorted_oracle(
