@@ -196,7 +196,7 @@ def hooi(
     from repro.engine.objective import resolve_objective
     from repro.engine.oracle import (choose_warm_start, resolve_block_size,
                                      resolve_warm_start)
-    from repro.engine.steps import local_mode_step
+    from repro.engine.steps import local_core_from_z, local_mode_step
     from repro.engine.sweep import run_hooi_sweeps
     from repro.engine.zbuild import resolve_fused_zbuild, resolve_precision
 
@@ -218,6 +218,10 @@ def hooi(
     blk = resolve_block_size(lanczos_block)
     fz = resolve_fused_zbuild(fused_zbuild)
     warm = resolve_warm_start(warm_start)
+    # the last mode's Z, kept for the sweep's core where it is built in f32
+    # (the executor's rule: distributed.executor._keeps_z)
+    keep_z = prec == "f32"
+    kept: dict = {}
 
     def mode_step(n, facs, kk):
         k_n = int(facs[n].shape[1])
@@ -233,11 +237,23 @@ def hooi(
         niter = lanczos_iters
         if niter is not None and (fz_n or s_eff > 1 or ws_n == "sketch"):
             niter = -(-int(niter) // s_eff)
-        return local_mode_step(coords, values, facs, n, t.shape[n], kk,
-                               niter=niter, use_kernel=use_kernels,
-                               use_fused_oracle=fused, precision=prec,
-                               block_size=s_eff, fused_zbuild=fz_n,
-                               warm_start=ws_n, objective=obj)
+        out = local_mode_step(coords, values, facs, n, t.shape[n], kk,
+                              niter=niter, use_kernel=use_kernels,
+                              use_fused_oracle=fused, precision=prec,
+                              block_size=s_eff, fused_zbuild=fz_n,
+                              warm_start=ws_n, objective=obj,
+                              return_z=keep_z and n == t.ndim - 1)
+        if isinstance(out, tuple):
+            out, kept["z"] = out
+        return out
+
+    # the rows the last mode's elements touch: the rows of the executor's
+    # local Z at P = 1
+    touched = np.unique(np.asarray(t.coords)[:, -1]) if keep_z else None
+
+    def core_from_z(factor):
+        return local_core_from_z(kept.pop("z"), touched, factor, t.ndim - 1,
+                                 [int(f.shape[1]) for f in factors])
 
     def on_sweep(it, _seconds, fit):  # pragma: no cover
         if verbose:
@@ -245,4 +261,5 @@ def hooi(
 
     return run_hooi_sweeps(coords, values, t, factors, key, n_invocations,
                            mode_step, on_sweep=on_sweep, objective=obj,
-                           metrics_out=metrics_out)
+                           metrics_out=metrics_out,
+                           core_from_z=core_from_z if keep_z else None)

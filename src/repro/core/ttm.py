@@ -153,13 +153,22 @@ def core_from_factors(
     coords: jnp.ndarray,
     values: jnp.ndarray,
     factors: Sequence[jnp.ndarray],
+    *,
+    from_z=None,
 ) -> jnp.ndarray:
     """Core G = T x_1 F_1^T x_2 ... x_N F_N^T  (paper Fig 2 last step).
 
     Computed element-wise: G = sum_e val(e) * outer(F_1[l_1], ..., F_N[l_N]),
     in chunks of ``ELEMENT_CHUNK`` elements. Returns a (K_1, ..., K_N)
     tensor.
+
+    ``from_z`` (a callable of the last factor), where the last mode step
+    kept its Z: the core is ``from_z(F_N)``, the paper's own last step
+    ``G_(N) = F_N^T Z_N`` folded to (K_1, ..., K_N), and the elements are
+    not folded (``HooiExecutor.run``; ``engine.steps.make_core_step_fn``).
     """
+    if from_z is not None:
+        return from_z(factors[-1])
     dims = tuple(int(f.shape[1]) for f in factors)
 
     def chunk_sum(acc, coords, values):

@@ -70,6 +70,7 @@ from repro.engine import (
     ARRAY_FIELDS,
     choose_warm_start,
     count_z_passes,
+    make_core_step_fn,
     make_mode_step_fn,
     make_stochastic_step_fn,
     make_zbuild_step_fn,
@@ -81,9 +82,10 @@ from repro.engine import (
     run_hooi_sweeps,
 )
 from repro.engine import zbuild as engine_zbuild
+from repro.engine.steps import local_core_step
 from repro.engine.objective import resolve_objective
 from repro.kernels import ops as kernel_ops
-from repro.kernels.window_segsum import pair_count, window_geometry
+from repro.kernels.window_segsum import pair_count
 from repro.tracing import Tally, hlo_scopes, span
 from .partition import comm_model, make_mode_partition  # noqa: F401 — re-export
 
@@ -143,6 +145,10 @@ class DistHooiStats:
     # on per element block, over all ranks: 1.0 when no block crosses a
     # window boundary (kernels.window_segsum.pair_count)
     z_pairs_per_block: dict | None = None
+    # column panels the windowed Z build launched, summed over the call's
+    # mode steps: one a mode step where K̂ fits one launch
+    # (kernels.ops.windowed_geometry)
+    z_panels: int = 0
     # mode -> comm backend the step ran ("local" | "psum" | "boundary")
     comm_backends: dict | None = None
     # True when the Lanczos oracle products ran the fused Pallas kernel
@@ -247,11 +253,20 @@ class _ModeSpec:
         return self.zbuild != "reference"
 
 
+def _keeps_z(n: int, N: int, precision: str) -> bool:
+    """Whether mode ``n``'s step keeps its Z for the sweep's core
+    (``engine.steps.make_core_step_fn``): the last mode's, where Z is built
+    in f32. A core from a bfloat16 build would carry its rounding, so the
+    core is folded from the elements there."""
+    return n == N - 1 and precision == "f32"
+
+
 def _pairs_per_block(mp, core_dims: Sequence[int], precision: str) -> float:
     """Windowed-kernel pairs per element block over every rank's elements
     of one mode partition (host count from the sorted local rows)."""
     Ka, Kb = kernel_ops.split_kron_dims(core_dims, mp.mode)
-    block_e = window_geometry(Ka, Kb, precision).block_e
+    block_e = kernel_ops.windowed_geometry(Ka, Kb,
+                                           precision=precision).block_e
     pairs = sum(pair_count(mp.local_rows[p], block_e) for p in range(mp.P))
     return pairs / (mp.P * -(-mp.E_pad // block_e))
 
@@ -288,6 +303,11 @@ class HooiExecutor:
         # (static sig, arg shapes) -> abstract arguments (op_scopes lowers
         # the step again from them)
         self._seen_shapes: dict[tuple, tuple] = {}
+        # the core executables (``_get_core_step``), kept apart from the
+        # mode steps, and their abstract arguments
+        self._cores: "collections.OrderedDict[tuple, object]" \
+            = collections.OrderedDict()
+        self._core_shapes: dict[tuple, tuple] = {}
         self._uploads: "weakref.WeakKeyDictionary[PartitionPlan, _PlanUpload]" \
             = weakref.WeakKeyDictionary()
         # an auto plan is a dataclasses.replace copy of its winning
@@ -431,7 +451,7 @@ class HooiExecutor:
                   precision: str = "f32", block_size: int = 1,
                   fused_zbuild: bool = False,
                   objective: str = "tucker",
-                  warm_start: str = "none") -> tuple:
+                  warm_start: str = "none", keep_z: bool = False) -> tuple:
         # the static signature of one mode step: everything baked into the
         # trace besides array shapes (which jit itself specializes on) —
         # the comm backend (or historical path alias), the Z-build variant
@@ -440,21 +460,25 @@ class HooiExecutor:
         # the objective, and the warm-start mode: distinct variants never
         # alias each other's compiled steps, so the rerun contract holds
         # per (objective, warm_start) variant.
+        # ``keep_z``: the last mode's step also returns its Z, which the
+        # sweep's core is taken from (engine.steps.make_core_step_fn)
         return (path, "kern" if use_kernel else "ref",
                 "fused" if use_fused else "plain", mp.mode, mp.R_pad,
                 mp.Lp, mp.S_pad, self.P, K_n, niter,
                 precision, int(block_size),
-                "fz" if fused_zbuild else "zb", objective, warm_start)
+                "fz" if fused_zbuild else "zb", objective, warm_start,
+                "keepz" if keep_z else "dropz")
 
     def _get_step(self, mp, path: str, K_n: int, use_kernel: bool = False,
                   niter: int | None = None, use_fused: bool = False,
                   precision: str = "f32", block_size: int = 1,
                   fused_zbuild: bool = False, objective: str = "tucker",
-                  warm_start: str = "none"):
+                  warm_start: str = "none", keep_z: bool = False):
         niter = 2 * K_n if niter is None else int(niter)
+        keep_z = keep_z and path != "zbuild"
         skey = self._step_key(mp, path, K_n, niter, use_kernel, use_fused,
                               precision, block_size, fused_zbuild, objective,
-                              warm_start)
+                              warm_start, keep_z)
         with self._lock:
             step = self._steps.get(skey)
             if step is not None:
@@ -466,7 +490,7 @@ class HooiExecutor:
                           use_fused=use_fused, precision=precision,
                           block_size=int(block_size),
                           fused_zbuild=fused_zbuild,
-                          warm_start=warm_start)
+                          warm_start=warm_start, keep_z=keep_z)
                 if path == "zbuild":
                     fn = make_zbuild_step_fn(ms, use_kernel,
                                              precision=precision)
@@ -481,19 +505,64 @@ class HooiExecutor:
                     smap = jax.shard_map(
                         fn, mesh=self.mesh, check_vma=False,
                         in_specs=(P("ranks"),) * 8 + (P(), P()),
-                        out_specs=(P("ranks"), P()),
+                        out_specs=(P("ranks"), P())
+                        + ((P("ranks"),) if ms["keep_z"] else ()),
                     )
                 step = jax.jit(smap)
-                self._steps[skey] = step
-                while len(self._steps) > MAX_COMPILED_STEPS:
-                    old = next(iter(self._steps))
-                    del self._steps[old]
-                    # a re-created callable gets a fresh jit cache: its
-                    # compilations must be counted again
-                    self._seen_shapes = {
-                        s: v for s, v in self._seen_shapes.items()
+                self._store_step(skey, step)
+        return skey, step
+
+    def _store_step(self, skey, step) -> None:
+        """Cache ``step`` under ``skey`` (lock held), dropping the least
+        recently used executables past ``MAX_COMPILED_STEPS``."""
+        self._steps[skey] = step
+        while len(self._steps) > MAX_COMPILED_STEPS:
+            old = next(iter(self._steps))
+            del self._steps[old]
+            # a re-created callable gets a fresh jit cache: its
+            # compilations must be counted again
+            self._seen_shapes = {s: v for s, v in self._seen_shapes.items()
+                                 if s[0] != old}
+
+    def _get_core_step(self, mp, backend: str, core_dims: Sequence[int]):
+        """Jitted ``shard_map`` of ``engine.make_core_step_fn`` for the
+        mode of ``mp``: the sweep's core from the Z its step kept. The
+        ``local`` backend (P = 1) takes ``engine.steps.local_core_step``,
+        the executable single-process ``hooi`` takes its core from."""
+        dims = tuple(int(k) for k in core_dims)
+        skey = ("core", backend, mp.mode, mp.R_pad, mp.Lp, mp.S_pad,
+                self.P, dims)
+        with self._lock:
+            step = self._cores.get(skey)
+            if step is not None:
+                self._cores.move_to_end(skey)
+            else:
+                if backend == "local":
+                    step = local_core_step(mp.mode, mp.R_pad, mp.Lp, dims)
+                else:
+                    ms = dict(mode=mp.mode, R_pad=mp.R_pad, Lp=mp.Lp,
+                              S_pad=mp.S_pad, P=mp.P)
+                    step = jax.jit(jax.shard_map(
+                        make_core_step_fn(ms, backend, dims), mesh=self.mesh,
+                        check_vma=False,
+                        in_specs=(P("ranks"),) * 6 + (P(), P()),
+                        out_specs=P()))
+                self._cores[skey] = step
+                while len(self._cores) > MAX_COMPILED_STEPS:
+                    old, _ = self._cores.popitem(last=False)
+                    self._core_shapes = {
+                        s: v for s, v in self._core_shapes.items()
                         if s[0] != old}
         return skey, step
+
+    def _call_core_step(self, skey, step, args: tuple):
+        # kept apart from the mode steps' shapes: a core is no step
+        # compilation, and ``op_scopes`` maps the steps alone
+        sig = (skey, tuple(a.shape for a in jax.tree.leaves(args)))
+        with self._lock:
+            if sig not in self._core_shapes:
+                self._core_shapes[sig] = jax.tree.map(_abstract, args)
+        return step(*args)
 
     def _note_shapes(self, skey, args: tuple, tally: dict) -> None:
         # jit compiles exactly when it first sees a shape signature for this
@@ -649,6 +718,21 @@ class HooiExecutor:
             out.update(hlo_scopes(step.lower(*args).compile().as_text()))
         return out
 
+    def core_ops(self) -> set[str]:
+        """``"<module>/<instruction>"`` of every instruction of the core
+        executables this executor has run (``jit_hooi_core_m<mode>_<backend>``,
+        traced whole under the scope ``core``), as ``op_scopes`` names the
+        steps' ops. Call it after the work it describes."""
+        with self._lock:
+            todo = [(self._cores[sig[0]], args)
+                    for sig, args in self._core_shapes.items()
+                    if sig[0] in self._cores]
+        out: set[str] = set()
+        for step, args in todo:
+            out.update(hlo_scopes(step.lower(*args).compile().as_text(),
+                                  names=("core",)))
+        return out
+
     def calibration_samples(self) -> list[dict]:
         """Measured sweeps (flops/bytes/seconds) for ``fit_cost_model``."""
         with self._lock:
@@ -734,7 +818,8 @@ class HooiExecutor:
                                         block_size=sp.block_size,
                                         fused_zbuild=sp.fused_zbuild,
                                         objective=sp.objective,
-                                        warm_start=sp.warm_start)
+                                        warm_start=sp.warm_start,
+                                        keep_z=_keeps_z(n, N, sp.precision))
             kk = jax.random.fold_in(key, 7000 + n)
             # register the shape signatures exactly like a run() would, so a
             # later run() on these shapes sees them as already-compiled (the
@@ -887,7 +972,8 @@ class HooiExecutor:
                                 block_size=specs[n].block_size,
                                 fused_zbuild=specs[n].fused_zbuild,
                                 objective=specs[n].objective,
-                                warm_start=specs[n].warm_start)
+                                warm_start=specs[n].warm_start,
+                                keep_z=_keeps_z(n, N, specs[n].precision))
                  for n in range(N)]
         with span("hooi.upload"):
             up = self._get_upload(pl, t, tally)
@@ -900,15 +986,21 @@ class HooiExecutor:
                     up.pairs_per_block[pkey] = _pairs_per_block(
                         parts[n], eff, prec)
                 z_pairs[n] = up.pairs_per_block[pkey]
+        z_panels = sum(kernel_ops.windowed_geometry(
+            *kernel_ops.split_kron_dims(eff, n), precision=prec).panels
+            for n in range(N) if specs[n].zbuild == "windowed")
         backend_label = _backend_label(specs)
         run_bytes = _run_comm_bytes(pl, specs)
 
         spectra: dict = {}
+        kept: dict = {}  # the last mode step's Z, until the core takes it
 
         def mode_step(n, facs, kk):
             skey, step = steps[n]
-            F_new, sv = self._call_step(skey, step, up.dev_args[n],
-                                        facs, kk, tally)
+            F_new, sv, *Z = self._call_step(skey, step, up.dev_args[n],
+                                            facs, kk, tally)
+            if Z:
+                kept["z"] = Z[0]
             # last-sweep spectrum estimate per mode (overwritten each
             # sweep) — the adaptive-rank policy reads its tail
             spectra[n] = sv
@@ -945,11 +1037,25 @@ class HooiExecutor:
                 })
             sweep_state["compiles"] = tally["step_compilations"]
 
+        def core_from_z(factor):
+            # G_(N) = U_Nᵀ Z_N from the Z the last step kept and the
+            # factor the sweep holds (after refine_factor); ``factors`` is
+            # the list the sweep loop updates
+            core_step = self._get_core_step(
+                parts[N - 1], specs[N - 1].backend,
+                [int(f.shape[1]) for f in factors])
+            args = (kept.pop("z"), *up.dev_args[N - 1][3:],
+                    up.row_perms[N - 1], factor)
+            return self._call_core_step(*core_step, args)
+
         objective_metrics: dict = {}
         dec, fits = run_hooi_sweeps(up.coords, up.values, t, factors, key,
                                     n_invocations, mode_step,
                                     on_sweep=on_sweep, objective=obj,
-                                    metrics_out=objective_metrics)
+                                    metrics_out=objective_metrics,
+                                    core_from_z=core_from_z if _keeps_z(
+                                        N - 1, N, specs[N - 1].precision)
+                                    else None)
 
         with self._lock:
             self._stats["runs"] += 1
@@ -970,6 +1076,7 @@ class HooiExecutor:
             z_kernel=z_kernel,
             z_build={n: specs[n].zbuild for n in range(N)},
             z_pairs_per_block=z_pairs or None,
+            z_panels=n_invocations * z_panels,
             comm_backends={n: specs[n].backend for n in range(N)},
             fused_oracle=fused,
             precision=prec,
@@ -1014,13 +1121,7 @@ class HooiExecutor:
                     int(mode), int(num_rows), int(K_n), int(niter),
                     int(block_size), use_kernel=use_kernel,
                     precision=precision))
-                self._steps[skey] = step
-                while len(self._steps) > MAX_COMPILED_STEPS:
-                    old = next(iter(self._steps))
-                    del self._steps[old]
-                    self._seen_shapes = {
-                        s: v for s, v in self._seen_shapes.items()
-                        if s[0] != old}
+                self._store_step(skey, step)
         return skey, step
 
     def _get_stoch_upload(self, t: SparseTensor, obj, sb,
@@ -1075,13 +1176,7 @@ class HooiExecutor:
                 from repro.core.ttm import core_from_factors
 
                 fn = jax.jit(core_from_factors)
-                self._steps[skey] = fn
-                while len(self._steps) > MAX_COMPILED_STEPS:
-                    old = next(iter(self._steps))
-                    del self._steps[old]
-                    self._seen_shapes = {
-                        s: v for s, v in self._seen_shapes.items()
-                        if s[0] != old}
+                self._store_step(skey, fn)
         return skey, fn
 
     @_traced_call

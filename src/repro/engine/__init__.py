@@ -55,6 +55,7 @@ from .scheduler import ScheduledResult, StreamScheduler
 from .steps import (
     ARRAY_FIELDS,
     local_mode_step,
+    make_core_step_fn,
     make_mode_step_fn,
     make_stochastic_step_fn,
     make_zbuild_step_fn,
@@ -98,6 +99,7 @@ __all__ = [
     "StreamScheduler",
     "ARRAY_FIELDS",
     "local_mode_step",
+    "make_core_step_fn",
     "make_mode_step_fn",
     "make_stochastic_step_fn",
     "make_zbuild_step_fn",

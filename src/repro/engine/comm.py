@@ -89,7 +89,10 @@ class OracleSpace:
     same way. ``wrap_matvec_out`` is the backend's placement step alone —
     ``matvec = wrap_matvec_out ∘ zmv`` — exposed so a fused Z-build stage
     that already holds the local product ``Z_local @ V_1`` can lift it into
-    the global oracle space without a second pass over Z.
+    the global oracle space without a second pass over Z. ``place_rows``
+    takes a value of every relabelled row, ``(P·Lp, ...)`` on every
+    device, to this device's u-space, so that ``rmatvec ∘ place_rows``
+    gives ``Zᵀ U`` of a whole factor (the core from the last mode's Z).
     """
 
     matvec: Callable  # x (K_hat,)|(K_hat, s) -> u-space vector/panel
@@ -98,6 +101,7 @@ class OracleSpace:
     axis: str | None  # mesh axis the u-space is sharded over (None: replicated)
     finalize: Callable  # left vectors (dim_u, k) -> per-device factor shard
     wrap_matvec_out: Callable = None  # local Z product -> u-space placement
+    place_rows: Callable = None  # (P·Lp, ...) replicated -> u-space
 
 
 def resolve_backend(path: str, P: int, comm: dict | None = None) -> str:
@@ -138,7 +142,7 @@ def _local_space(ms: dict, arrs: dict, zmv, zrmv) -> OracleSpace:
         return zrmv(u.at[row_gid].get(mode="fill", fill_value=0.0))
 
     return OracleSpace(lambda x: wrap(zmv(x)), rmatvec, Lp, None,
-                       lambda left: left, wrap)
+                       lambda left: left, wrap, lambda rows: rows)
 
 
 def _psum_space(ms: dict, arrs: dict, zmv, zrmv) -> OracleSpace:
@@ -163,7 +167,7 @@ def _psum_space(ms: dict, arrs: dict, zmv, zrmv) -> OracleSpace:
         return jax.lax.dynamic_slice_in_dim(left, p * Lp, Lp, 0)
 
     return OracleSpace(lambda x: wrap(zmv(x)), rmatvec, L_sent, None,
-                       finalize, wrap)
+                       finalize, wrap, lambda rows: rows)
 
 
 def _boundary_space(ms: dict, arrs: dict, zmv, zrmv) -> OracleSpace:
@@ -207,8 +211,11 @@ def _boundary_space(ms: dict, arrs: dict, zmv, zrmv) -> OracleSpace:
         with jax.named_scope("comm"):
             return jax.lax.psum(part, AXIS)
 
+    def place_rows(rows):  # every relabelled row -> this device's shard
+        return jax.lax.dynamic_slice_in_dim(rows, p * Lp, Lp, 0)
+
     return OracleSpace(lambda x: wrap(zmv(x)), rmatvec, Lp, AXIS,
-                       lambda left: left, wrap)
+                       lambda left: left, wrap, place_rows)
 
 
 _SPACES = {

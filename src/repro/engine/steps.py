@@ -14,6 +14,10 @@ for its executable (``hooi_step_m<mode>_<backend>``):
   part of the executor's upload-cache contract.
 * ``make_zbuild_step_fn`` — the Z-build-only probe for per-phase
   calibration.
+* ``make_core_step_fn`` — the core from the last mode's Z (paper Fig. 2,
+  last step: ``G₍N₎ = U_Nᵀ Z_N``), through that step's ``OracleSpace``
+  under the scope ``core``; the last mode step keeps its Z for it
+  (``ms["keep_z"]``).
 * ``local_mode_step`` — the same composition with the identity partition
   and the ``local`` backend semantics, no ``shard_map``: this is what
   ``repro.core.hooi`` runs, making the single-process reference the P=1
@@ -34,11 +38,13 @@ from repro.core.lanczos import (block_start_panel, gk_block_bidiag,
 from repro.core.sketch import (DEFAULT_POWER_ITERS, power_refine,
                                seeded_start_panel, sketch_block_size,
                                sketch_niter)
+from repro.core.ttm import fold
 from .comm import AXIS, make_comm_space
 from .oracle import solve_oracle, solve_oracle_block, z_products
 from .zbuild import build_local_z, build_local_z_oracle
 
-__all__ = ["make_mode_step_fn", "make_zbuild_step_fn", "local_mode_step",
+__all__ = ["make_mode_step_fn", "make_zbuild_step_fn", "make_core_step_fn",
+           "local_mode_step", "local_core_step", "local_core_from_z",
            "make_stochastic_step_fn", "ARRAY_FIELDS"]
 
 # the per-device ModePartition arrays a distributed step consumes, in the
@@ -92,7 +98,8 @@ def make_mode_step_fn(ms: dict, backend: str, K_n: int, niter: int):
     """One distributed mode step for ``shard_map`` over the 'ranks' axis.
 
     ``ms`` is the static partition signature (mode, R_pad, Lp, S_pad, P,
-    use_kernel, use_fused, precision, block_size, fused_zbuild, warm_start);
+    use_kernel, use_fused, precision, block_size, fused_zbuild, warm_start,
+    keep_z);
     ``backend`` one of ``engine.comm``'s names. All of these are baked into
     the trace — the executor keys its compiled-step cache on them. ``niter``
     counts block iterations when ``block_size > 1``.
@@ -107,11 +114,15 @@ def make_mode_step_fn(ms: dict, backend: str, K_n: int, niter: int):
     it cannot be served by the fused build's pre-Z first product —
     ``fused_zbuild`` is structurally off for sketch modes (the spec builder
     normalizes it; asserted here).
+
+    ``keep_z`` (the last mode's step) returns the device's Z as a third
+    output, ``(1, R_pad, K_hat)``, for ``make_core_step_fn``.
     """
     precision = ms.get("precision", "f32")
     block_size = int(ms.get("block_size", 1))
     fused_zbuild = bool(ms.get("fused_zbuild", False))
     warm_start = ms.get("warm_start", "none")
+    keep_z = bool(ms.get("keep_z", False))
     assert not (fused_zbuild and warm_start == "sketch"), \
         "sketch warm start excludes the fused first product (spec builder)"
 
@@ -174,9 +185,49 @@ def make_mode_step_fn(ms: dict, backend: str, K_n: int, niter: int):
                 left, S = solve_oracle(space.matvec, space.rmatvec,
                                        space.dim_u, Z.shape[1], K_n, niter,
                                        key, axis=space.axis)
+            if keep_z:
+                return space.finalize(left), S, Z[None]
             return space.finalize(left), S
 
     return _named(fn, f"hooi_step_m{ms['mode']}_{backend}")
+
+
+def make_core_step_fn(ms: dict, backend: str, core_dims: Sequence[int]):
+    """The core from the Z a mode step kept: ``G₍n₎ = U_nᵀ Z_n`` folded to
+    ``core_dims`` (paper Fig. 2, last step), for ``shard_map`` over the
+    'ranks' axis like the mode step of ``ms``.
+
+    ``fn(Z, row_gid, row_owned, bnd_slot, own_bnd_slot, own_bnd_off,
+    row_perm, factor)``: the device's Z from ``make_mode_step_fn``
+    (``keep_z``), the step's five row arrays, ``row_perm`` (original row ->
+    relabelled row) and the mode's factor in original row order, the one
+    the sweep holds after the step (an objective's ``refine_factor``
+    included). The factor is laid out over the relabelled rows and taken
+    through the step's own ``OracleSpace``: ``rmatvec`` of the K-wide
+    panel, summed over ranks as the oracle's products are, so no rank
+    folds the elements and no ``(elements, ∏K)`` temporary exists. Runs
+    under the scope ``core``.
+    """
+    mode = ms["mode"]
+    dims = tuple(int(k) for k in core_dims)
+
+    @f32_matmuls
+    def fn(Z, row_gid, row_owned, bnd_slot, own_bnd_slot, own_bnd_off,
+           row_perm, factor):
+        (Z, row_gid, row_owned, bnd_slot, own_bnd_slot, own_bnd_off) = (
+            x[0] for x in (Z, row_gid, row_owned, bnd_slot, own_bnd_slot,
+                           own_bnd_off))
+        arrs = dict(row_gid=row_gid, row_owned=row_owned, bnd_slot=bnd_slot,
+                    own_bnd_slot=own_bnd_slot, own_bnd_off=own_bnd_off)
+        with jax.named_scope("core"):
+            _, zrmv = z_products(Z)
+            space = make_comm_space(backend, ms, arrs, None, zrmv)
+            rows = jnp.zeros((ms["P"] * ms["Lp"], factor.shape[1]),
+                             factor.dtype).at[row_perm].set(factor)
+            gz = space.rmatvec(space.place_rows(rows))  # Z_nᵀ U_n
+            return fold(gz.T, mode, dims)
+
+    return _named(fn, f"hooi_core_m{mode}_{backend}")
 
 
 def make_stochastic_step_fn(mode: int, num_rows: int, K_n: int, niter: int,
@@ -243,10 +294,12 @@ def local_mode_step(
     fused_zbuild: bool = False,
     warm_start: str = "none",
     objective=None,
+    return_z: bool = False,
 ) -> jnp.ndarray:
     """One single-process mode step (identity partition, local backend).
 
-    Returns the refined factor (num_rows, k). The Z build runs under the
+    Returns the refined factor (num_rows, k); with ``return_z``, ``(factor,
+    Z)`` for ``local_core_from_z``. The Z build runs under the
     named scope ``zbuild`` and the solve under ``oracle``, as in the
     distributed steps.
 
@@ -317,4 +370,31 @@ def local_mode_step(
             left, S = res.left_vectors, res.singular_values
     if objective is not None:
         left = objective.refine_factor(left, S)
-    return left
+    return (left, Z) if return_z else left
+
+
+@functools.lru_cache(maxsize=64)
+def local_core_step(mode: int, R_pad: int, Lp: int, core_dims: tuple):
+    """``make_core_step_fn`` of the ``local`` backend, jitted: the one
+    executable that gives the core at P = 1, in ``HooiExecutor.run`` (a
+    one-device ``shard_map`` is the identity, so none is wrapped around it)
+    and in single-process ``hooi`` (``local_core_from_z``), so that both
+    paths compute it alike, to the bit."""
+    ms = dict(mode=mode, R_pad=R_pad, Lp=Lp, S_pad=1, P=1)
+    return jax.jit(make_core_step_fn(ms, "local", core_dims))
+
+
+def local_core_from_z(Z: jnp.ndarray, touched, factor: jnp.ndarray,
+                      mode: int, core_dims: Sequence[int]) -> jnp.ndarray:
+    """Single-process ``hooi``'s core from the last mode's Z: the identity
+    partition's ``local_core_step`` on the rows the mode's elements touch
+    (``touched``, sorted ascending; the rows the executor's local Z holds
+    at P = 1). The local backend reads ``row_gid`` alone of the step's
+    row arrays; the others are given at their P = 1 shapes."""
+    R, L = len(touched), int(factor.shape[0])
+    rows = jnp.asarray(touched, jnp.int32)
+    step = local_core_step(mode, R, L, tuple(int(k) for k in core_dims))
+    return step(Z[rows][None], rows[None], jnp.ones((1, R), bool),
+                jnp.ones((1, R), jnp.int32), jnp.ones((1, 1), jnp.int32),
+                jnp.full((1, 1), L, jnp.int32),
+                jnp.arange(L, dtype=jnp.int32), factor)

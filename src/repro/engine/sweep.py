@@ -48,6 +48,7 @@ def run_hooi_sweeps(
     on_sweep: Callable[[int, float, float], None] | None = None,
     objective=None,
     metrics_out: dict | None = None,
+    core_from_z: Callable[[jnp.ndarray], jnp.ndarray] | None = None,
 ):
     """Run ``n_invocations`` HOOI sweeps, returning (Decomposition, fits).
 
@@ -64,6 +65,12 @@ def run_hooi_sweeps(
     ``TuckerObjective`` reproduces it bitwise, so both arms are the same
     trajectory. ``metrics_out`` collects the objective's extra per-sweep
     stats (e.g. completion's held-out RMSE).
+
+    ``core_from_z(F_N) -> core``, where the last mode step keeps its Z (the
+    executor's steps), gives each sweep's core from that Z and the factor
+    the step returned: ``core_from_factors(..., from_z=core_from_z)``, the
+    paper's ``G_(N) = U_Nᵀ Z_N``. Without it, and for ``n_invocations=0``,
+    the core is folded from the elements.
     """
     from repro.core.hooi import Decomposition, fit_score
     from repro.core.ttm import core_from_factors
@@ -83,7 +90,8 @@ def run_hooi_sweeps(
                 jax.block_until_ready(factors)
             sweep_s += sp.seconds
             with span("hooi.core", it=it):
-                core = core_from_factors(coords, values, factors)
+                core = core_from_factors(coords, values, factors,
+                                         from_z=core_from_z)
                 if objective is not None:
                     core = objective.finalize_core(core, factors)
             with span("hooi.fit", it=it):

@@ -29,13 +29,13 @@ from . import ref
 from .kron_segsum import (  # noqa: F401
     ROW_BLOCK, kron_segsum, kron_segsum_oracle, tile_geometry)
 from .oracle_fused import oracle_pair as _oracle_pair_kernel
-from .window_segsum import (chunk_pairs, mode_pairs, window_geometry,
-                            window_segsum)
+from .window_segsum import (WindowGeometry, chunk_pairs, mode_pairs,
+                            panel_widths, window_geometry, window_segsum)
 
 __all__ = ["penultimate", "penultimate_local", "penultimate_sorted",
            "penultimate_sorted_oracle", "oracle_pair", "kernel_fits_vmem",
-           "windowed_fits_vmem", "window_fold_step", "split_kron_dims",
-           "vmem_budget_bytes"]
+           "windowed_fits_vmem", "windowed_geometry", "window_fold_step",
+           "split_kron_dims", "vmem_budget_bytes"]
 
 # default admission budget for one launch's VMEM footprint
 # (``TileGeometry.vmem_bytes``), in bytes: a quarter of a v5e core's
@@ -78,13 +78,28 @@ def kernel_fits_vmem(num_rows: int, Ka: int, Kb: int,
     return geom.vmem_bytes <= budget
 
 
+def windowed_geometry(Ka: int, Kb: int, *, precision: str = "f32",
+                      vmem_budget: int | None = None
+                      ) -> WindowGeometry | None:
+    """The windowed kernel's launch for K̂ = Ka·Kb, or None where no launch
+    fits the budget: all K̂ columns in one launch where that fits, else the
+    widest column panel (``window_segsum.panel_widths``) that does. The
+    footprint (``WindowGeometry.vmem_bytes``) depends on the columns a
+    launch builds, not on the number of rows."""
+    budget = vmem_budget_bytes() if vmem_budget is None else vmem_budget
+    for panel in [None] + panel_widths(Ka, Kb):
+        geom = window_geometry(Ka, Kb, precision, panel)
+        if geom.vmem_bytes <= budget:
+            return geom
+    return None
+
+
 def windowed_fits_vmem(Ka: int, Kb: int, *, precision: str = "f32",
                        vmem_budget: int | None = None) -> bool:
-    """Admission gate of the windowed kernel: its footprint
-    (``WindowGeometry.vmem_bytes``) depends on Ka·Kb alone, not on the
-    number of rows."""
-    budget = vmem_budget_bytes() if vmem_budget is None else vmem_budget
-    return window_geometry(Ka, Kb, precision).vmem_bytes <= budget
+    """Admission gate of the windowed kernel: some launch of it, whole or
+    in column panels, fits the budget (``windowed_geometry``)."""
+    return windowed_geometry(Ka, Kb, precision=precision,
+                             vmem_budget=vmem_budget) is not None
 
 
 def split_kron_dims(core_dims: Sequence[int], mode: int) -> tuple[int, int]:
@@ -170,15 +185,17 @@ def window_fold_step(local_rows: jnp.ndarray, factors: Sequence[jnp.ndarray],
                      mode: int, num_rows: int, *, precision: str = "f32"):
     """The step of the sorted-rows Z build's ``fold_elements`` loop over
     the carry ``(z, start)``: ``step((z, start), coords, values, rows)``
-    adds the chunk of elements from ``start`` through the windowed kernel
-    and moves ``start`` past it. The (element block, row window) pairs of
-    the whole mode are computed here, once, and each step slices its
+    adds the chunk of elements from ``start`` through the windowed kernel,
+    in the launches ``windowed_geometry`` gives (one, or one per column
+    panel), and moves ``start`` past it. The (element block, row window)
+    pairs of the whole mode are computed here, once, and each step slices its
     chunk's, so chunks must start on block boundaries: ``block_e`` divides
     ``core.ttm.ELEMENT_CHUNK``. The factor-row gathers and the leading
     Kronecker levels (``_split_ab``) stay in XLA; ``z`` is the kernel's
     aliased output."""
     Ka, Kb = split_kron_dims([int(f.shape[1]) for f in factors], mode)
-    block_e = window_geometry(Ka, Kb, precision).block_e
+    geom = windowed_geometry(Ka, Kb, precision=precision)
+    block_e = geom.block_e
     assert ELEMENT_CHUNK % block_e == 0, (ELEMENT_CHUNK, block_e)
     local_rows = local_rows.astype(jnp.int32)
     pairs = (mode_pairs(local_rows, block_e, num_rows)
@@ -192,7 +209,8 @@ def window_fold_step(local_rows: jnp.ndarray, factors: Sequence[jnp.ndarray],
         z = window_segsum(
             rows.astype(jnp.int32), a, b, z,
             chunk_pairs(pairs, start, rows.shape[0], block_e, num_rows),
-            interpret=_interpret_default(), precision=precision)
+            interpret=_interpret_default(), precision=precision,
+            panel=geom.panel)
         return z, start + rows.shape[0]
 
     return step

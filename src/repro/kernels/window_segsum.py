@@ -22,6 +22,15 @@ and a window no pair visits keeps what Z held (zero on the first chunk of
 a mode, the earlier chunks' sum after that). VMEM per launch depends on
 ``block_e`` and K̂ only (``WindowGeometry.vmem_bytes``), not on R.
 
+Where a ``(W, K̂)`` window and the K̂-row expansion matrices would not fit
+VMEM (K̂ = 10⁴ at five modes of K = 10), the build runs over column panels
+of Z: one launch per panel, each the launch above for ``panel`` columns of
+Z, the ``panel / Kb`` rows of ``aᵀ`` that make them and expansion matrices
+of ``panel`` rows. A panel's width is a multiple of 128 lanes and of
+``8·Kb`` (whole, sublane-aligned rows of ``aᵀ``); the last panel may
+overhang K̂, and its rows of ``aᵀ`` past Ka read as zero. Z stays one
+``(R, K̂)`` array, aliased through the launches.
+
 C is built K̂ = Ka·Kb rows deep, element by element in lanes (``Cᵀ``), with
 no padding of Kb: the kernel takes ``a`` and ``b`` as ``(Ka, E)`` and
 ``(Kb, E)``, elements along lanes, so that the XLA gathers that make them
@@ -48,6 +57,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -55,10 +65,10 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .kron_segsum import DEFAULT_SCOPED_VMEM, ROW_BLOCK, _lanes
+from .kron_segsum import DEFAULT_SCOPED_VMEM, LANE, ROW_BLOCK, _lanes
 
-__all__ = ["window_segsum", "window_geometry", "window_pairs",
-           "mode_pairs", "chunk_pairs", "pair_count", "split_pieces",
+__all__ = ["window_segsum", "window_geometry", "panel_widths",
+           "window_pairs", "mode_pairs", "chunk_pairs", "pair_count", "split_pieces",
            "expansion_matrices", "packed_ct", "WindowGeometry", "WINDOW"]
 
 WINDOW = ROW_BLOCK  # rows of Z one grid step updates
@@ -75,9 +85,9 @@ def _sublanes(n: int, itemsize: int) -> int:
 
 @dataclasses.dataclass(frozen=True)
 class WindowGeometry:
-    """Block size and VMEM footprint of one windowed launch.
+    """Block size, column panel and VMEM footprint of one windowed launch.
 
-    The admission gate (``ops.windowed_fits_vmem``) and the kernel derive
+    The admission gate (``ops.windowed_geometry``) and the kernel derive
     their shapes from here. Nothing depends on the number of rows.
     """
 
@@ -85,10 +95,21 @@ class WindowGeometry:
     Kb: int
     block_e: int
     pieces: int  # bf16 pieces per f32 operand: 3 (f32), 1 (bf16)
+    panel: int  # columns of Z one launch builds (K̂: a single launch)
 
     @property
     def khat(self) -> int:
         return self.Ka * self.Kb
+
+    @property
+    def panels(self) -> int:
+        """Launches per chunk: column panels covering K̂."""
+        return -(-self.khat // self.panel)
+
+    @property
+    def panel_rows(self) -> int:
+        """Rows of ``aᵀ`` one launch reads (Ka for a single launch)."""
+        return self.panel // self.Kb
 
     @property
     def vmem_bytes(self) -> int:
@@ -102,14 +123,15 @@ class WindowGeometry:
         * temporaries: the operand pieces, the two f32 expansions, Cᵀ and
           its remainder, the one-hot and its compare, the window update.
         """
-        B, kl, p = self.block_e, _lanes(self.khat), self.pieces
-        ab_rows = _sublanes(self.Ka, 4) + _sublanes(self.Kb, 4)
+        B, W, p = self.block_e, self.panel, self.pieces
+        kl = _lanes(W)
+        ab_rows = _sublanes(self.panel_rows, 4) + _sublanes(self.Kb, 4)
         inputs = 2 * (8 * B * 4 + ab_rows * B * 4
-                      + _sublanes(self.khat, 2)
-                      * (_lanes(self.Ka) + _lanes(self.Kb)) * 2)
+                      + _sublanes(W, 2)
+                      * (_lanes(self.panel_rows) + _lanes(self.Kb)) * 2)
         z_windows = 2 * 2 * WINDOW * kl * 4
-        c_pieces = p * _sublanes(self.khat, 2) * B * 2
-        temps = (ab_rows * B * (4 + 2 * 3) + 4 * _sublanes(self.khat, 4) * B * 4
+        c_pieces = p * _sublanes(W, 2) * B * 2
+        temps = (ab_rows * B * (4 + 2 * 3) + 4 * _sublanes(W, 4) * B * 4
                  + WINDOW * B * (4 + 2) + 2 * WINDOW * kl * 4)
         return inputs + z_windows + c_pieces + temps
 
@@ -120,16 +142,33 @@ class WindowGeometry:
         return max(DEFAULT_SCOPED_VMEM, self.vmem_bytes * 5 // 4)
 
 
-def window_geometry(Ka: int, Kb: int, precision: str = "f32"
-                    ) -> WindowGeometry:
-    """The launch geometry for K̂ = Ka·Kb: ``block_e`` is the power of two
-    in [128, 2048] nearest below ``2**18 / lanes(K̂)`` (2,048 elements at
-    K̂ = 100, 256 at K̂ = 1,000)."""
+def window_geometry(Ka: int, Kb: int, precision: str = "f32",
+                    panel: int | None = None) -> WindowGeometry:
+    """The launch geometry for K̂ = Ka·Kb built ``panel`` columns a launch
+    (None: all K̂ in one). ``block_e`` is the power of two in [128, 2048]
+    nearest below ``2**18 / lanes(panel)`` (2,048 elements at K̂ = 100,
+    256 at K̂ = 1,000)."""
+    panel = Ka * Kb if panel is None else int(panel)
+    if panel != Ka * Kb and (panel % math.lcm(LANE, 8 * Kb)
+                             or panel >= Ka * Kb):
+        raise ValueError(f"a panel of {panel} columns is no multiple of "
+                         f"lcm(128, 8·{Kb}) below K̂ = {Ka * Kb}")
     block_e = _BLOCK_MAX
-    while block_e > _BLOCK_MIN and block_e * _lanes(Ka * Kb) > _BLOCK_ENTRIES:
+    while block_e > _BLOCK_MIN and block_e * _lanes(panel) > _BLOCK_ENTRIES:
         block_e //= 2
     return WindowGeometry(Ka=Ka, Kb=Kb, block_e=block_e,
-                          pieces=1 if precision == "bf16" else 3)
+                          pieces=1 if precision == "bf16" else 3,
+                          panel=panel)
+
+
+def panel_widths(Ka: int, Kb: int) -> list[int]:
+    """The column panels narrower than K̂ a launch can take, widest first:
+    multiples of ``lcm(128, 8·Kb)`` whose ``aᵀ`` rows fill at most one
+    128-deep MXU pass, so that the expansion of ``aᵀ`` costs no more than
+    the one-hot product."""
+    unit = math.lcm(LANE, 8 * Kb)
+    widest = LANE * Kb // unit * unit
+    return [w for w in range(widest, 0, -unit) if w < Ka * Kb]
 
 
 def _truncate_bf16(x: jnp.ndarray) -> jnp.ndarray:
@@ -270,15 +309,19 @@ _ELEMENTS = ((1,), (1,))  # one-hot (W, B) against Cᵀ (K̂, B): contract B
 
 
 def _kernel(blk_ref, win_ref, rows_ref, at_ref, bt_ref, ea_ref, eb_ref,
-            zin_ref, zout_ref, c_ref, *, pieces: int, block_e: int):
+            zin_ref, zout_ref, c_ref, *, pieces: int, block_e: int,
+            a_rows: int | None):
     p = pl.program_id(0)
     prev = jnp.maximum(p - 1, 0)
     win = win_ref[p]
 
     @pl.when((p == 0) | (blk_ref[p] != blk_ref[prev]))
     def _build_c():
-        ct = packed_ct(at_ref[...], bt_ref[...], ea_ref[...], eb_ref[...],
-                       pieces)
+        at = at_ref[...]
+        if a_rows is not None:  # a last panel past Ka: those rows are zero
+            at = jnp.where(jax.lax.broadcasted_iota(jnp.int32, at.shape, 0)
+                           < a_rows, at, 0.0)
+        ct = packed_ct(at, bt_ref[...], ea_ref[...], eb_ref[...], pieces)
         for k, piece in enumerate(split_pieces(ct, pieces)):
             c_ref[k] = piece
 
@@ -295,7 +338,8 @@ def _kernel(blk_ref, win_ref, rows_ref, at_ref, bt_ref, ea_ref, eb_ref,
     zout_ref[...] += upd
 
 
-@functools.partial(jax.jit, static_argnames=("interpret", "precision"))
+@functools.partial(jax.jit,
+                   static_argnames=("interpret", "precision", "panel"))
 def window_segsum(
     rows: jnp.ndarray,  # (E,) int32 — dense local row ids, SORTED ascending
     a: jnp.ndarray,  # (E, Ka) float32 — values folded in
@@ -305,6 +349,7 @@ def window_segsum(
     *,
     interpret: bool = True,
     precision: str = "f32",
+    panel: int | None = None,
 ) -> jnp.ndarray:
     """``z + segment_sum(kron(a, b), rows)``, shape (R, Ka·Kb).
 
@@ -315,14 +360,17 @@ def window_segsum(
     a chunked build computed them for the whole mode (``window_pairs`` of
     the rows otherwise). ``a`` and ``b`` reach the kernel transposed, so a
     caller that gathers them lets XLA lay the gathers out element-major.
+    ``panel`` is the columns of Z one launch builds (``panel_widths``;
+    None: all of them in one launch).
     """
     E, Ka = a.shape
     Kb = b.shape[1]
     R, khat = z.shape
     if E == 0:
         return z
-    geom = window_geometry(Ka, Kb, precision)
-    B, pieces = geom.block_e, geom.pieces
+    geom = window_geometry(Ka, Kb, precision, panel)
+    B, pieces, W, rows_a = (geom.block_e, geom.pieces, geom.panel,
+                            geom.panel_rows)
 
     E_pad = -(-E // B) * B
     at, bt = a.astype(jnp.float32).T, b.astype(jnp.float32).T
@@ -333,31 +381,38 @@ def window_segsum(
         bt = jnp.pad(bt, ((0, 0), (0, pad)))
     rows = rows.astype(jnp.int32)
     blk, win, n_pairs = window_pairs(rows, B, R) if pairs is None else pairs
-    ea, eb = expansion_matrices(Ka, Kb)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(n_pairs,),
-        in_specs=[
-            pl.BlockSpec((1, B), lambda p, blk, win: (0, blk[p])),  # rows
-            pl.BlockSpec((Ka, B), lambda p, blk, win: (0, blk[p])),
-            pl.BlockSpec((Kb, B), lambda p, blk, win: (0, blk[p])),
-            pl.BlockSpec((khat, Ka), lambda p, blk, win: (0, 0)),
-            pl.BlockSpec((khat, Kb), lambda p, blk, win: (0, 0)),
-            pl.BlockSpec((WINDOW, khat), lambda p, blk, win: (win[p], 0)),
-        ],
-        out_specs=pl.BlockSpec((WINDOW, khat),
-                               lambda p, blk, win: (win[p], 0)),
-        scratch_shapes=[pltpu.VMEM((pieces, khat, B), jnp.bfloat16)],
-    )
-    return pl.pallas_call(
-        functools.partial(_kernel, pieces=pieces, block_e=B),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((R, khat), jnp.float32),
-        input_output_aliases={7: 0},
-        interpret=interpret,
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",),
-            vmem_limit_bytes=geom.vmem_limit_bytes),
-        name="window_segsum",
-    )(blk, win, rows[None, :], at, bt, ea, eb, z.astype(jnp.float32))
+    # every panel starts on a row of aᵀ, so all take the same matrices
+    ea, eb = expansion_matrices(rows_a, Kb)
+    z = z.astype(jnp.float32)
+    for c in range(geom.panels):
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(n_pairs,),
+            in_specs=[
+                pl.BlockSpec((1, B), lambda p, blk, win: (0, blk[p])),  # rows
+                pl.BlockSpec((rows_a, B),
+                             lambda p, blk, win, c=c: (c, blk[p])),
+                pl.BlockSpec((Kb, B), lambda p, blk, win: (0, blk[p])),
+                pl.BlockSpec((W, rows_a), lambda p, blk, win: (0, 0)),
+                pl.BlockSpec((W, Kb), lambda p, blk, win: (0, 0)),
+                pl.BlockSpec((WINDOW, W),
+                             lambda p, blk, win, c=c: (win[p], c)),
+            ],
+            out_specs=pl.BlockSpec((WINDOW, W),
+                                   lambda p, blk, win, c=c: (win[p], c)),
+            scratch_shapes=[pltpu.VMEM((pieces, W, B), jnp.bfloat16)],
+        )
+        a_rows = Ka - c * rows_a
+        z = pl.pallas_call(
+            functools.partial(_kernel, pieces=pieces, block_e=B,
+                              a_rows=a_rows if a_rows < rows_a else None),
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((R, khat), jnp.float32),
+            input_output_aliases={7: 0},
+            interpret=interpret,
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary",),
+                vmem_limit_bytes=geom.vmem_limit_bytes),
+            name="window_segsum",
+        )(blk, win, rows[None, :], at, bt, ea, eb, z)
+    return z

@@ -36,7 +36,8 @@ __all__ = ["span", "Span", "Tally", "open_spans", "hlo_scopes", "SCOPES",
 
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
-# device scopes named inside the mode steps, innermost wins
+# device scopes named inside the mode steps, innermost wins (the core
+# executables run under one more, ``core``: HooiExecutor.core_ops)
 SCOPES = ("zbuild", "oracle", "comm")
 
 _Annotation = jax.profiler.TraceAnnotation
@@ -137,21 +138,23 @@ _OP_NAME = re.compile(r'op_name="([^"]*)"')
 _MODULE = re.compile(r"^HloModule\s+([\w.\-]+)")
 
 
-def _scope_of(op_name: str) -> str | None:
+def _scope_of(op_name: str, names) -> str | None:
     found = None
     for part in op_name.split("/"):
-        if part in SCOPES:
+        if part in names:
             found = part
     return found
 
 
-def hlo_scopes(hlo_text: str) -> dict[str, str]:
+def hlo_scopes(hlo_text: str, names=SCOPES) -> dict[str, str]:
     """``{"<module>/<instruction>": scope}`` of a compiled HLO module's text.
 
-    An instruction takes the innermost of ``SCOPES`` in its ``op_name``
-    metadata (a fusion: its own metadata). One that carries none (a copy or
-    layout change XLA inserted) takes the scope of its first operand that
-    has one. Instructions left without a scope are not listed.
+    An instruction takes the innermost of ``names`` (the mode steps'
+    ``SCOPES`` unless given; ``("core",)`` for the core executables) in its
+    ``op_name`` metadata (a fusion: its own metadata). One that carries
+    none (a copy or layout change XLA inserted) takes the scope of its
+    first operand that has one. Instructions left without a scope are not
+    listed.
     """
     module = ""
     scopes: dict[str, str] = {}
@@ -167,7 +170,7 @@ def hlo_scopes(hlo_text: str) -> dict[str, str]:
             continue
         name = m.group(1)
         meta = _OP_NAME.search(line)
-        scope = _scope_of(meta.group(1)) if meta else None
+        scope = _scope_of(meta.group(1), names) if meta else None
         if scope is not None:
             scopes[name] = scope
         else:

@@ -5,8 +5,9 @@ refuses: unaligned tiles, too much VMEM, a kernel that cannot be lowered.
 These tests compile ``kron_segsum``, ``kron_segsum_oracle`` and
 ``oracle_pair`` at nell2-s widths (E = 77,000 elements, K = 10, R rows per
 rank at P = 1), and ``window_segsum`` at the benchmark cells' widths (one
-65,536-element chunk into nell-2's and enron's largest mode), for one
-device of a described ``v5e:2x2`` topology and check that the Pallas kernel
+65,536-element chunk into nell-2's and enron's largest mode, and in
+column panels the chunked build of chicago-crime-geo's longest mode at
+K̂ = 10⁴), for one device of a described ``v5e:2x2`` topology and check that the Pallas kernel
 is in the compiled program. Keep them in this one
 file: the topology is described in a module fixture, in the process that
 runs the tests.
@@ -117,6 +118,44 @@ def test_window_segsum_compiles_for_v5e(one_chip, no_persistent_cache, cell,
                             _spec(one_chip, (CHUNK, Ka)),
                             _spec(one_chip, (CHUNK, Kb)),
                             _spec(one_chip, (rows, Ka * Kb)))
+
+
+def test_panelled_window_segsum_compiles_for_v5e(one_chip,
+                                                no_persistent_cache,
+                                                monkeypatch):
+    """Five modes at K = 10 (Ka × Kb = 1,000 × 10, K̂ = 10⁴): the chunked
+    build of chicago-crime-geo's longest mode (two chunks and a remainder)
+    takes the gate's column panels, each a launch within its scoped VMEM
+    limit, with Z the loop's carry and the launches' aliased output, never
+    copied, padded or sliced."""
+    from repro.engine.steps import f32_matmuls
+    from repro.engine.zbuild import build_local_z
+    from repro.kernels import ops
+
+    monkeypatch.setattr(ops, "_interpret_default", lambda: False)
+    geom = ops.windowed_geometry(1000, 10)
+    assert geom.panels > 1
+    shape = (6185, 24, 380, 395, 32)
+    R, E = shape[0], 2 * CHUNK + 1000
+
+    def fn(coords, values, local_rows, *factors):
+        Z = f32_matmuls(build_local_z)(coords, values, local_rows, factors,
+                                       0, R, use_kernel=True)
+        return Z.sum(axis=0)
+
+    compiled = jax.jit(fn).lower(
+        _spec(one_chip, (E, 5), jnp.int32), _spec(one_chip, (E,)),
+        _spec(one_chip, (E,), jnp.int32),
+        *[_spec(one_chip, (L, K)) for L in shape]).compile()
+    text = compiled.as_text()
+    assert text.count("custom_call_target=\"tpu_custom_call\"") \
+        >= 2 * geom.panels
+    z = re.compile(r"= f32\[6185,10000\]\S* (copy|pad|slice)\(")
+    assert not [ln for ln in text.splitlines() if z.search(ln)]
+    # Z (247 MB) and one chunk's temporaries, a few of its aᵀ (262 MB)
+    at_bytes = CHUNK * 1000 * 4
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < R * 10_000 * 4 + 4 * at_bytes
 
 
 def test_windowed_build_updates_z_in_place(one_chip, no_persistent_cache,

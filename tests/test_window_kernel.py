@@ -228,7 +228,11 @@ def test_geometry_vmem_does_not_depend_on_rows():
     assert (g100.block_e, g1000.block_e) == (2048, 256)
     assert g1000.vmem_bytes <= ops.vmem_budget_bytes()
     assert ops.windowed_fits_vmem(100, 10)
-    assert not ops.windowed_fits_vmem(1000, 100)  # K̂ = 100,000
+    # K̂ = 100,000 takes column panels, each launch within the budget; a
+    # K̂ whose narrowest panel does not fit stays out
+    wide = ops.windowed_geometry(1000, 100)
+    assert wide.panels > 1 and wide.vmem_bytes <= ops.vmem_budget_bytes()
+    assert not ops.windowed_fits_vmem(100, 1000)
 
 
 def test_resolve_zbuild_routes_sorted_plain_builds_to_the_window():
